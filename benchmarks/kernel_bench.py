@@ -1,7 +1,8 @@
 """Kernel micro-benchmarks.
 
-On this CPU container the Pallas kernels execute in interpret mode, so
-wall-clock is meaningless; what is reported instead:
+Off the TPU the Pallas kernels execute in interpret mode
+(`kernels.ops.use_interpret`), so wall-clock is meaningless; what is
+reported instead:
   * correctness deltas vs the jnp oracles (allclose margins);
   * the *analytic* VMEM working set per BlockSpec configuration vs the
     16 MiB/core budget (the quantity that determines real TPU viability);
@@ -14,10 +15,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.admission.functional_qos import QoSState, make_qos, qos_take
 from repro.core.functional import live_fifo_rank, live_fifo_rank_pairwise
 from repro.kernels.decode_attention import decode_attention
+from repro.kernels.ops import use_interpret
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.qos_admission import qos_round_fused
 from repro.kernels.ref import (
@@ -44,8 +45,8 @@ def qos_vmem(block_n, s_pad, u_pad, table):
 
 
 def _flops(fn, *args):
-    return compat.cost_analysis(
-        jax.jit(fn).lower(*args).compile()).get("flops", 0.0)
+    return (jax.jit(fn).lower(*args).compile().cost_analysis()
+            or {}).get("flops", 0.0)
 
 
 def flash_vmem(block_q, block_k, hd, G):
@@ -59,6 +60,7 @@ def flash_vmem(block_q, block_k, hd, G):
 def run(metrics: dict | None = None) -> str:
     lines = ["== Pallas kernels (interpret-mode validation + VMEM budgets) =="]
     key = jax.random.PRNGKey(0)
+    interp = use_interpret()
 
     # flash attention configs: (name, S, H, KV, hd, bq, bk)
     for name, S, H, KV, hd, bq, bk in [
@@ -70,7 +72,7 @@ def run(metrics: dict | None = None) -> str:
         q = jax.random.normal(ks[0], (1, S, H, hd), jnp.bfloat16)
         k = jax.random.normal(ks[1], (1, S, KV, hd), jnp.bfloat16)
         v = jax.random.normal(ks[2], (1, S, KV, hd), jnp.bfloat16)
-        out = flash_attention_fwd(q, k, v, block_q=bq, block_k=bk, interpret=True)
+        out = flash_attention_fwd(q, k, v, block_q=bq, block_k=bk, interpret=interp)
         ref = mha_ref(q, k, v)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
         G = H // KV
@@ -95,7 +97,7 @@ def run(metrics: dict | None = None) -> str:
         v = jax.random.normal(ks[2], (2, C, KV, hd), jnp.bfloat16)
         pos = jnp.broadcast_to(jnp.arange(C)[None], (2, C)).astype(jnp.int32)
         qp = jnp.full((2,), C, jnp.int32)
-        out = decode_attention(q, k, v, pos, qp, block_k=bk, interpret=True)
+        out = decode_attention(q, k, v, pos, qp, block_k=bk, interpret=interp)
         ref = decode_attention_ref(q, k, v, pos, qp)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
         G = H // KV
@@ -123,7 +125,7 @@ def run(metrics: dict | None = None) -> str:
     offs = jnp.asarray([0, 24, 7, 40], jnp.int32)
     lens = jnp.asarray([32, 32, 17, 0], jnp.int32)
     out_k, kp2, vp2 = paged_prefill(qp_, kc, vc, kpool, vpool, tbl, offs,
-                                    lens, interpret=True)
+                                    lens, interpret=interp)
     out_r, kpr, vpr = paged_prefill_ref(qp_, kc, vc, kpool, vpool, tbl,
                                         offs, lens)
     pf_exact = bool(np.array_equal(np.asarray(out_k), np.asarray(out_r))
@@ -145,7 +147,7 @@ def run(metrics: dict | None = None) -> str:
     # sema_batch
     req = jax.random.bernoulli(key, 0.6, (2048,))
     out = sema_batch(jnp.uint32(0), jnp.uint32(64), jnp.zeros((1024,), jnp.uint32),
-                     req, jnp.uint32(128), jnp.uint32(7), block_n=512, interpret=True)
+                     req, jnp.uint32(128), jnp.uint32(7), block_n=512, interpret=interp)
     ref = sema_batch_ref(jnp.uint32(0), jnp.uint32(64), jnp.zeros((1024,), jnp.uint32),
                          req, jnp.uint32(128), jnp.uint32(7))
     exact = bool(np.array_equal(np.asarray(out[4]), np.asarray(ref["admitted"])))
@@ -165,7 +167,7 @@ def run(metrics: dict | None = None) -> str:
                                rng.uniform(0, 2, N), np.inf), jnp.float32)
     ref = qos_round_ref(qs, ids, tk, alive, dls, 1.0, 24, MU)
     ks, ka, ke, kl = qos_round_fused(qs, ids, tk, alive, dls, 1.0, 24,
-                                     max_units=MU, block_n=BN, interpret=True)
+                                     max_units=MU, block_n=BN, interpret=interp)
     qexact = (
         np.array_equal(np.asarray(ka), np.asarray(ref["admitted"]))
         and np.array_equal(np.asarray(ke), np.asarray(ref["expired"]))
@@ -214,7 +216,7 @@ def run(metrics: dict | None = None) -> str:
 
     def _profile(name, fn, *args):
         try:
-            ca = compat.cost_analysis(jax.jit(fn).lower(*args).compile())
+            ca = jax.jit(fn).lower(*args).compile().cost_analysis() or {}
         except Exception as e:  # pragma: no cover - backend-specific
             lines.append(f"profile {name}: cost analysis unavailable ({e})")
             ca = {}
@@ -230,16 +232,16 @@ def run(metrics: dict | None = None) -> str:
     _profile("qos_round_fused",
              lambda st, i, t, a, d: qos_round_fused(
                  st, i, t, a, d, 1.0, 24, max_units=MU, block_n=BN,
-                 interpret=True),
+                 interpret=interp),
              qs, ids, tk, alive, dls)
     pd_q = jax.random.normal(key, (tbl.shape[0], H, hd), jnp.float32)
     pd_lens = jnp.asarray([32, 17, 9, 0], jnp.int32)
     _profile("paged_decode",
              lambda q_, kp_, vp_, t_, l_: paged_decode(
-                 q_, kp_, vp_, t_, l_, interpret=True),
+                 q_, kp_, vp_, t_, l_, interpret=interp),
              pd_q, kpool, vpool, tbl, pd_lens)
     _profile("paged_prefill",
-             lambda *a: paged_prefill(*a, interpret=True),
+             lambda *a: paged_prefill(*a, interpret=interp),
              qp_, kc, vc, kpool, vpool, tbl, offs, lens)
     return "\n".join(lines)
 

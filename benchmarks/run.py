@@ -31,6 +31,9 @@ def main(argv=None):
                     help="CI smoke mode: skip the K=128 megastep sweep and "
                          "shrink the paged-pool workload (flat wall time)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     if args.quick:
         import os
 

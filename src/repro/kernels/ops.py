@@ -1,14 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute via interpret=True; on TPU they
-compile natively.  The model code keeps the pure-jnp path as default (the
-512-device host dry-run cannot lower Pallas); serving/benchmarks opt in via
-use_pallas=True or REPRO_USE_PALLAS=1.
+The kernels compile natively on a TPU and run in interpret mode on every
+other backend; `use_interpret` is the one place that choice is made.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import numpy as np
@@ -23,21 +19,22 @@ from .qos_admission import qos_round_scan as _qos_round_scan
 from .sema_batch import sema_batch as _sema_batch
 
 
-def _interpret() -> bool:
+def use_interpret() -> bool:
+    """Pallas interpret mode off the TPU, native kernels on it."""
     return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=512, block_k=512):
     return _flash_attention_fwd(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=_interpret(),
+        block_q=block_q, block_k=block_k, interpret=use_interpret(),
     )
 
 
 def decode_attention(q, k, v, kv_pos, q_pos, *, window=0, block_k=512):
     return _decode_attention(
         q, k, v, kv_pos, q_pos, window=window, block_k=block_k,
-        interpret=_interpret(),
+        interpret=use_interpret(),
     )
 
 
@@ -45,7 +42,7 @@ def paged_decode(q, k_pool, v_pool, block_tbl, lens):
     """Ragged flash-decode over the block-paged KV pool (oracle:
     `ref.paged_decode_ref`, bit-exact in interpret mode)."""
     return _paged_decode(q, k_pool, v_pool, block_tbl, lens,
-                         interpret=_interpret())
+                         interpret=use_interpret())
 
 
 def paged_prefill(q, k_chunk, v_chunk, k_pool, v_pool, block_tbl, off, lens):
@@ -54,13 +51,13 @@ def paged_prefill(q, k_chunk, v_chunk, k_pool, v_pool, block_tbl, off, lens):
     (aliased pools), causal-within-chunk + full prior-block attention
     (oracle: `ref.paged_prefill_ref`, bit-exact in interpret mode)."""
     return _paged_prefill(q, k_chunk, v_chunk, k_pool, v_pool, block_tbl,
-                          off, lens, interpret=_interpret())
+                          off, lens, interpret=use_interpret())
 
 
 def sema_batch(ticket, grant, bucket_seq, requests, post_n, salt, *, block_n=512):
     return _sema_batch(
         ticket, grant, bucket_seq, requests, post_n, salt,
-        block_n=block_n, interpret=_interpret(),
+        block_n=block_n, interpret=use_interpret(),
     )
 
 
@@ -91,7 +88,7 @@ def qos_round(state, tenant_ids, tickets, alive, deadlines, now, free_units,
                                       block_n)
     state2, admitted, expired, leftover = _qos_round_fused(
         state, ids, tks, alv, dls, now, free_units,
-        max_units=max_units, block_n=block_n, interpret=_interpret())
+        max_units=max_units, block_n=block_n, interpret=use_interpret())
     return state2, admitted[:n], expired[:n], leftover
 
 
@@ -107,9 +104,5 @@ def qos_round_scan(state, tenant_ids, tickets, alive, deadlines, nows,
                                       block_n)
     state2, admit_round, expire_round, free = _qos_round_scan(
         state, ids, tks, alv, dls, nows, free_units, released,
-        max_units=max_units, block_n=block_n, interpret=_interpret())
+        max_units=max_units, block_n=block_n, interpret=use_interpret())
     return state2, admit_round[:n], expire_round[:n], free
-
-
-def pallas_enabled() -> bool:
-    return os.environ.get("REPRO_USE_PALLAS", "0") == "1" or jax.default_backend() == "tpu"

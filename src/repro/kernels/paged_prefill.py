@@ -69,21 +69,23 @@ def _prefill_kernel(tbl_ref, off_ref, len_ref, q_ref, kc_ref, vc_ref,
     @pl.when((i * BS < off + ln) & (ln > 0))  # ragged bound: skip dead blocks
     def _block():
         q = q_ref[0, 0]          # (G·CT, hd) — GQA groups stacked row-major
-        tpos = i * BS + jax.lax.iota(jnp.int32, BS)
+        # block-row token positions as a column (merge) and a row (mask)
+        tcol = i * BS + jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0)
+        trow = i * BS + jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
         # merge this block's slice of the chunk K/V (freshly-taken blocks
         # get their rows here — the in-pass writeback), then attend over
         # the MERGED content: the partially-filled boundary block serves
         # both its old rows and the chunk's new ones in one fetch
-        sel, ku = paged_prefill_merge(kc_ref[0, 0], tpos, off, ln)
-        _, vu = paged_prefill_merge(vc_ref[0, 0], tpos, off, ln)
-        kblk = jnp.where(sel[:, None], ku, kp_ref[0, 0])
-        vblk = jnp.where(sel[:, None], vu, vp_ref[0, 0])
+        sel, ku = paged_prefill_merge(kc_ref[0, 0], tcol, off, ln)
+        _, vu = paged_prefill_merge(vc_ref[0, 0], tcol, off, ln)
+        kblk = jnp.where(sel, ku, kp_ref[0, 0])
+        vblk = jnp.where(sel, vu, vp_ref[0, 0])
         ko_ref[0, 0] = kblk
         vo_ref[0, 0] = vblk
         rows = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) \
             % chunk_cap
         qpos = off + rows
-        mask = (rows < ln) & (tpos[None, :] <= qpos)  # causal + ragged
+        mask = (rows < ln) & (trow <= qpos)  # causal + ragged
         m, l, acc = flash_prefill_block(
             q, kblk, vblk, mask, m_ref[...], l_ref[...], acc_ref[...],
             scale=scale)

@@ -49,6 +49,20 @@ from ..core.hashfn import MIX32KA
 _INF_BITS = 0x7F800000  # f32 +inf bit pattern (crossings are ≥ 0)
 
 
+def _col(mask):
+    """(n,) bool → (n, 1) bool.  Mosaic cannot reshape a 1-bit vector
+    into a column, so the mask makes the trip as int32."""
+    return mask.astype(jnp.int32)[:, None] != 0
+
+
+def _count(mask, axis):
+    """Count of set entries along ``axis`` as uint32.  Mosaic reduces no
+    unsigned integers, so the sum runs in int32; counts stay far below
+    2³¹, so the value equals the uint32 sum and the caller's u32 add
+    keeps its mod-2³² wrap."""
+    return jnp.sum(mask.astype(jnp.int32), axis=axis).astype(jnp.uint32)
+
+
 def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
                 ids_ref, alive_ref, dl_ref,
                 adm_ref, exp_ref, out_u_ref, out_vp_ref, out_seq_ref,
@@ -70,7 +84,7 @@ def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
 
     scols = jax.lax.broadcasted_iota(jnp.int32, (block_n, s_pad), 1)
     onehot = scols == ids[:, None]  # (block_n, Sp)
-    oh_alive = onehot & alive2[:, None]
+    oh_alive = onehot & _col(alive2)
     cnt_alive = jnp.sum(oh_alive.astype(jnp.int32), axis=0)  # (Sp,)
 
     @pl.when((p == 0) & (j == 0))
@@ -81,8 +95,7 @@ def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
     @pl.when(p == 0)
     def _sweep_depth():
         depth_ref[0] = depth_ref[0] + cnt_alive
-        deadb_ref[0] = deadb_ref[0] + jnp.sum(
-            (onehot & newly[:, None]).astype(jnp.uint32), axis=0)
+        deadb_ref[0] = deadb_ref[0] + _count(onehot & _col(newly), 0)
         # every output block is fully written each visit (revisited at p=1)
         adm_ref[0] = jnp.zeros((block_n,), jnp.int32)
         exp_ref[0] = newly.astype(jnp.int32)
@@ -97,7 +110,8 @@ def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
         unmet = jnp.clip(depth_ref[0] - avail0, 0, max_units)
 
         # crossing matrix: value of tenant s's k-th grant in virtual time
-        kf = jax.lax.broadcasted_iota(jnp.float32, (s_pad, u_pad), 1)
+        kf = jax.lax.broadcasted_iota(jnp.int32, (s_pad, u_pad), 1).astype(
+            jnp.float32)
         step = jnp.where(weight[:, None] > 0, kf / weight[:, None], jnp.inf)
         step = jnp.where(kf == 0, 0.0, step)  # k=0 crossing is vpass itself
         cross = jnp.where(kf < unmet[:, None].astype(jnp.float32),
@@ -130,11 +144,12 @@ def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
             tri, eq_s.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(jnp.int32)
         extra = jnp.clip(rem - exc, 0, eq_s)
-        alloc = (lt_s + extra).astype(jnp.uint32)
+        alloc_i = lt_s + extra
+        alloc = alloc_i.astype(jnp.uint32)
 
         alloc_ref[0] = alloc
-        availr_ref[0] = avail0 + alloc.astype(jnp.int32)
-        af = alloc.astype(jnp.float32)
+        availr_ref[0] = avail0 + alloc_i
+        af = alloc_i.astype(jnp.float32)
         out_vp_ref[0] = vpass + jnp.where(
             alloc > 0, jnp.where(weight > 0, af / weight, jnp.inf), 0.0)
 
@@ -151,8 +166,7 @@ def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
         tsalt = salt + (srows + 1) * jnp.uint32(MIX32KA)  # == tenant_salt
         start = twa_hash_u32(tsalt, grant[:, None])
         offs = ((jcols - start) * jnp.uint32(STRIDE_INV)) & jnp.uint32(table - 1)
-        out_seq_ref[0] = seq_ref[0] + jnp.sum(
-            (offs < width[:, None]).astype(jnp.uint32), axis=0)
+        out_seq_ref[0] = seq_ref[0] + _count(offs < width[:, None], 0)
 
         carry_ref[0] = jnp.zeros((s_pad,), jnp.int32)
         spent_ref[0] = jnp.zeros((s_pad,), jnp.uint32)
@@ -174,8 +188,7 @@ def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
         adm_ref[0] = admitted.astype(jnp.int32)
         exp_ref[0] = newly.astype(jnp.int32)
         carry_ref[0] = base + cnt_alive
-        spent_ref[0] = spent_ref[0] + jnp.sum(
-            (onehot & admitted[:, None]).astype(jnp.uint32), axis=0)
+        spent_ref[0] = spent_ref[0] + _count(onehot & _col(admitted), 0)
 
     @pl.when((p == 1) & (j == nb - 1))
     def _fin():
@@ -188,11 +201,14 @@ def _qos_kernel(scal_u_ref, scal_i_ref, nowf_ref, wf_ref, st_ref, seq_ref,
         surplus = jnp.maximum(avail_after - depth_after, 0).astype(jnp.uint32)
         out_u_ref[0] = grant + alloc
         out_u_ref[1] = st_ref[2] + spent + surplus
-        out_u_ref[2] = dead0 - jnp.minimum(dead0, surplus)  # frontier decay
+        # frontier decay: dead0 − min(dead0, surplus), written as a select
+        # because Mosaic has no unsigned min
+        out_u_ref[2] = jnp.where(dead0 > surplus, dead0 - surplus,
+                                 jnp.uint32(0))
         out_u_ref[3] = alloc
         leftover = (free - jnp.sum(alloc.astype(jnp.int32))
                     + jnp.sum(surplus.astype(jnp.int32)))
-        out_scal_ref[...] = jnp.zeros((8,), jnp.int32).at[0].set(leftover)
+        out_scal_ref[0] = leftover
 
 
 def _roundup(x: int, m: int) -> int:
@@ -251,9 +267,9 @@ def qos_round_fused(state, tenant_ids, tickets, alive, deadlines, now,
                           max_units=max_units, u_pad=u_pad),
         grid=(2, nb),
         in_specs=[
-            pl.BlockSpec((8,), lambda p, j: (0,)),
-            pl.BlockSpec((8,), lambda p, j: (0,)),
-            pl.BlockSpec((8,), lambda p, j: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((2, s_pad), lambda p, j: (0, 0)),
             pl.BlockSpec((4, s_pad), lambda p, j: (0, 0)),
             pl.BlockSpec((1, T), lambda p, j: (0, 0)),
@@ -267,7 +283,7 @@ def qos_round_fused(state, tenant_ids, tickets, alive, deadlines, now,
             pl.BlockSpec((4, s_pad), lambda p, j: (0, 0)),
             pl.BlockSpec((1, s_pad), lambda p, j: (0, 0)),
             pl.BlockSpec((1, T), lambda p, j: (0, 0)),
-            pl.BlockSpec((8,), lambda p, j: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, N + pad), jnp.int32),   # admitted

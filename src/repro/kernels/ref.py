@@ -236,18 +236,19 @@ def paged_prefill_merge(chunk, tpos, off, length):
     blockwise oracle `paged_prefill_ref`, so bit-exactness pins the
     writeback logic, not fp noise.
 
-    ``chunk``: (CT, hd) this slot's chunk K or V rows; ``tpos``: (BS,) i32
-    absolute token positions of the block rows; ``off``/``length``: chunk
-    start position / token count.  Block row t receives chunk row
+    ``chunk``: (CT, hd) this slot's chunk K or V rows; ``tpos``: (BS, 1)
+    i32 absolute token positions of the block rows, as a column (Mosaic
+    cannot turn a 1-bit vector into one); ``off``/``length``: chunk start
+    position / token count.  Block row t receives chunk row
     ``tpos[t] − off`` iff it falls inside the chunk window.  The gather is
     a 0/1 one-hot matmul — MXU-friendly on TPU and EXACT in f32 (each
     output row is a single product with a 1.0) — instead of a dynamic
-    in-kernel gather.  Returns ``(sel (BS,) bool, upd (BS, hd))``."""
+    in-kernel gather.  Returns ``(sel (BS, 1) bool, upd (BS, hd))``."""
     CT = chunk.shape[0]
     sel = (tpos >= off) & (tpos < off + length)
     c = tpos - off
-    onehot = ((c[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, CT), 1))
-              & sel[:, None]).astype(jnp.float32)
+    onehot = ((c == jax.lax.broadcasted_iota(jnp.int32, (1, CT), 1))
+              & sel).astype(jnp.float32)
     upd = jax.lax.dot_general(
         onehot, chunk.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -330,10 +331,10 @@ def paged_prefill_ref(q, k_chunk, v_chunk, k_pool, v_pool, block_tbl, off,
             m, l, acc, mk, mv = carry
             b = jnp.maximum(trow[i], 0)          # the kernel's index-map clamp
             tpos = i * BS + jnp.arange(BS, dtype=jnp.int32)
-            sel, ku = paged_prefill_merge(kcrow, tpos, o, ln)
-            _, vu = paged_prefill_merge(vcrow, tpos, o, ln)
-            kblk = jnp.where(sel[:, None], ku, kp[h, b])
-            vblk = jnp.where(sel[:, None], vu, vp[h, b])
+            sel, ku = paged_prefill_merge(kcrow, tpos[:, None], o, ln)
+            _, vu = paged_prefill_merge(vcrow, tpos[:, None], o, ln)
+            kblk = jnp.where(sel, ku, kp[h, b])
+            vblk = jnp.where(sel, vu, vp[h, b])
             mask = qvalid & (tpos[None, :] <= qpos)
             m2, l2, acc2 = flash_prefill_block(
                 qrow, kblk, vblk, mask, m, l, acc, scale=scale)

@@ -43,54 +43,61 @@ TICKET_STRIDE = 17
 def _sema_kernel(scal_ref, req_ref, seq_ref, tickets_ref, admitted_ref,
                  bucket_ref, woken_ref, new_scal_ref, new_seq_ref,
                  base_ref, *, table, block_n):
+    # scalars (ticket, grant, post_n, salt) and the running ticket base live
+    # in SMEM; per-request rows are (1, block_n) lane vectors, and every
+    # reduction runs in int32 (Mosaic reduces no unsigned integers)
     i = pl.program_id(0)
-    ticket0 = scal_ref[0]
     grant = scal_ref[1]
     post_n = scal_ref[2]
     salt = scal_ref[3]
 
     @pl.when(i == 0)
     def _init():
-        base_ref[0, 0] = ticket0
+        base_ref[0] = scal_ref[0]
 
-    req = req_ref[0].astype(jnp.float32)  # (block_n,)
+    req_i = req_ref[...]  # (1, block_n) i32
+    req = req_i.astype(jnp.float32)
     # exclusive prefix rank via strict-lower-triangular matmul (MXU):
+    # ranks[0, r] = Σ_c tri[r, c]·req[0, c] = # requests before row r
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 1)
     tri = (cols < rows).astype(jnp.float32)
     ranks = jax.lax.dot_general(
-        tri, req, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # ranks[r] = # requests before row r (within block)
-    base = base_ref[0, 0]
-    tickets = base + ranks.astype(jnp.uint32)
-    reqb = req_ref[0] != 0
+        req, tri, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (1, block_n)
+    base = base_ref[0]
+    tickets = base + ranks.astype(jnp.int32).astype(jnp.uint32)
+    reqb = req_i != 0
     admitted = reqb & ((grant - tickets).astype(jnp.int32) > 0)
 
-    idx = ((salt + tickets * jnp.uint32(TICKET_STRIDE)) & jnp.uint32(table - 1)).astype(jnp.int32)
+    mask = jnp.uint32(table - 1)
+    idx = ((salt + tickets * jnp.uint32(TICKET_STRIDE)) & mask).astype(jnp.int32)
 
     # post side: bump buckets of the enabled ticket range [grant, grant+n)
-    offs = jax.lax.broadcasted_iota(jnp.uint32, (1, table), 1)[0]
-    enabled = (offs < post_n).astype(jnp.uint32)
-    post_idx = ((salt + (grant + offs) * jnp.uint32(TICKET_STRIDE)) & jnp.uint32(table - 1))
+    offs = jax.lax.broadcasted_iota(jnp.int32, (table, 1), 0).astype(jnp.uint32)
+    enabled = offs < post_n  # (T, 1)
+    post_idx = ((salt + (grant + offs) * jnp.uint32(TICKET_STRIDE)) & mask
+                ).astype(jnp.int32)
     # permutation one-hot reduction: bump[j] = Σ_i enabled[i]·[post_idx_i == j]
-    tcols = jax.lax.broadcasted_iota(jnp.uint32, (table, table), 1)
-    onehot = (post_idx[:, None] == tcols).astype(jnp.uint32)
-    bump = jnp.sum(onehot * enabled[:, None], axis=0)  # (table,)
-    new_seq = seq_ref[0] + bump
+    tcols = jax.lax.broadcasted_iota(jnp.int32, (table, table), 1)
+    bump = jnp.sum(jnp.where((post_idx == tcols) & enabled, 1, 0),
+                   axis=0, keepdims=True)  # (1, T) i32
+    new_seq = seq_ref[...] + bump.astype(jnp.uint32)
 
     # gather bump at each waiter's bucket (compare-select, no scatter/gather)
-    bcols = jax.lax.broadcasted_iota(jnp.int32, (block_n, table), 1)
-    bump_at = jnp.sum(jnp.where(bcols == idx[:, None], bump[None, :], 0), axis=1)
+    brows = jax.lax.broadcasted_iota(jnp.int32, (table, block_n), 0)
+    bump_at = jnp.sum(jnp.where(brows == idx, bump.T, 0), axis=0,
+                      keepdims=True)  # (1, block_n)
     woken = reqb & (bump_at > 0)
 
-    tickets_ref[0] = tickets
-    admitted_ref[0] = admitted.astype(jnp.int32)
-    bucket_ref[0] = idx
-    woken_ref[0] = woken.astype(jnp.int32)
-    new_seq_ref[0] = new_seq
+    tickets_ref[...] = tickets
+    admitted_ref[...] = admitted.astype(jnp.int32)
+    bucket_ref[...] = idx
+    woken_ref[...] = woken.astype(jnp.int32)
+    new_seq_ref[...] = new_seq
 
-    n_req = jnp.sum(req).astype(jnp.uint32)
-    base_ref[0, 0] = base + n_req
+    n_req = jnp.sum(req_i).astype(jnp.uint32)
+    base_ref[0] = base + n_req
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _fin():
@@ -119,7 +126,7 @@ def sema_batch(ticket, grant, bucket_seq, requests, post_n, salt,
         functools.partial(_sema_kernel, table=T, block_n=block_n),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((4,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((1, T), lambda i: (0, 0)),
         ],
@@ -128,7 +135,7 @@ def sema_batch(ticket, grant, bucket_seq, requests, post_n, salt,
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
-            pl.BlockSpec((4,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, T), lambda i: (0, 0)),
         ],
         out_shape=[
@@ -139,7 +146,7 @@ def sema_batch(ticket, grant, bucket_seq, requests, post_n, salt,
             jax.ShapeDtypeStruct((4,), jnp.uint32),           # new scalars
             jax.ShapeDtypeStruct((1, T), jnp.uint32),         # new bucket_seq
         ],
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.uint32)],
+        scratch_shapes=[pltpu.SMEM((1,), jnp.uint32)],
         interpret=interpret,
     )(scal, reqp.reshape(1, -1), bucket_seq.reshape(1, -1))
 

@@ -35,7 +35,6 @@ from pathlib import Path  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from .. import compat  # noqa: E402
 from ..configs.registry import SHAPES, get_config, shapes_for  # noqa: E402
 from ..optim.adamw import AdamWConfig  # noqa: E402
 from ..parallel import steps as steps_lib  # noqa: E402
@@ -180,7 +179,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mode: str,
     }
     t0 = time.time()
     HBM_BUDGET = 14.5e9  # v5e 16 GB minus runtime reserve
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         dp = steps_lib.dp_size()
         sc = steps_lib.default_step_config(cfg, shape, dp, analysis=(mode == "analysis"))
         max_accum = max(1, shape.global_batch // max(dp, 1))
@@ -245,7 +244,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mode: str,
                     continue
             break
 
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         rec["cost"] = {"flops": ca.get("flops", 0.0),
                        "bytes_accessed": ca.get("bytes accessed", 0.0),
                        "transcendentals": ca.get("transcendentals", 0.0)}
@@ -265,7 +264,7 @@ def _measure_analysis(cfg, shape, mesh, hlo_path=None, sc_over=None) -> dict:
                                 is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
     compiled = jax.jit(step, in_shardings=in_shardings,
                        donate_argnums=donate).lower(*args).compile()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     text = compiled.as_text()
     if hlo_path:
         Path(hlo_path).parent.mkdir(parents=True, exist_ok=True)
@@ -317,7 +316,7 @@ def run_analysis(arch: str, shape_name: str, mesh_kind: str,
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_kind, "mode": "analysis",
            "chips": n_chips, "ok": False}
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         U = cfg.num_units
         hlo = (Path(hlo_dir) / f"{arch}_{shape_name}_{mesh_kind}.hlo") if hlo_dir else None
         if U <= 4:

@@ -1,4 +1,8 @@
-"""Production meshes.
+"""Device meshes — the one place this repo builds a `jax.sharding.Mesh`.
+
+Every axis is `AxisType.Auto`: the sharding rules (`parallel/sharding.py`)
+steer the partitioner with `with_sharding_constraint`, which only accepts
+Auto axes (`jax.make_mesh` defaults to Explicit ones).
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — required because the dry-run must set
@@ -9,6 +13,14 @@ XLA_FLAGS before any jax initialization, while smoke tests want the plain
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,14 +30,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     compressed gradient reduction) — it crosses DCI, not ICI."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_dev_mesh(n_data: int = 2, n_model: int = 2, n_pod: int = 0):
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
     if n_pod:
-        return jax.make_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def mesh_device_count(*, multi_pod: bool = False) -> int:

@@ -1,6 +1,8 @@
 """Serving driver: continuous batching with TWA-semaphore FCFS admission
-over a real (reduced) model — the paper's technique as the first-class
-scheduler of an inference engine.
+over a real model — the paper's technique as the first-class scheduler of
+an inference engine.  The CLI serves the reduced (smoke) config of an
+architecture; `ModelServer` serves whatever config and dtype its caller
+gives it (``chip_smoke.py`` serves full-width qwen2-0.5b in bf16).
 
     python -m repro.launch.serve --arch qwen2-0.5b --requests 24 --slots 4
 """
@@ -14,50 +16,92 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..configs.base import ModelConfig
 from ..configs.registry import get_smoke_config
-from ..models.transformer import decode_step, init_caches, init_params, prefill
+from ..models.transformer import (
+    decode_step,
+    init_caches,
+    init_params,
+    keep_cache_rows,
+    reset_cache_row,
+)
 from ..serving.scheduler import ContinuousBatchingEngine, Request
+from .compile_cache import setup_compile_cache
 
 
 class ModelServer:
-    """Slot-synchronous batched decode over a reduced config."""
+    """Slot-synchronous batched decode of ``cfg`` with weights and caches
+    in ``dtype`` (random weights from ``seed``).
 
-    def __init__(self, arch: str, n_slots: int, max_len: int = 128, seed: int = 0):
-        self.cfg = get_smoke_config(arch)
+    Every decode call advances the KV ring cursor of every row by one entry
+    (`models.cache.make_kv_cache`), so rows that take no part in a call
+    write an empty entry (position -1) and keep their recurrent states, a
+    new request starts from a reset row, and a request's entries stay in
+    the ring for ``max_len`` calls: a call that would overwrite a live
+    request's oldest entry raises instead."""
+
+    def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int = 128,
+                 seed: int = 0, dtype=jnp.float32):
+        self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
-        self.params = init_params(jax.random.PRNGKey(seed), self.cfg)
-        self.caches = init_caches(self.cfg, n_slots, max_len, jnp.float32)
+        self.params = init_params(jax.random.PRNGKey(seed), cfg, dtype)
+        self.caches = init_caches(cfg, n_slots, max_len, dtype)
         self.tokens = np.zeros((n_slots, 1), np.int32)
         self.positions = np.zeros((n_slots, 1), np.int32)
         self.row_pos = np.zeros((n_slots,), np.int32)
-        self._decode = jax.jit(
-            lambda p, t, pos, c: decode_step(p, self.cfg, t, pos, c)
-        )
+        self._calls = 0  # decode calls so far == every cache's ring cursor
+        self._born = np.zeros((n_slots,), np.int64)  # call at row's prefill
+        self._decode = jax.jit(self._decode_rows)
+        self._reset_row = jax.jit(lambda c, row: reset_cache_row(cfg, c, row))
+
+    def _decode_rows(self, params, tokens, positions, caches):
+        logits, new = decode_step(params, self.cfg, tokens, positions, caches)
+        return logits, keep_cache_rows(self.cfg, new, caches, positions[:, 0] >= 0)
+
+    def _call(self, rows):
+        """One decode call in which only ``rows`` write live entries."""
+        if (self._calls - self._born[rows] >= self.max_len).any():
+            raise RuntimeError(
+                f"the {self.max_len}-entry KV ring would overwrite a live "
+                "request's entries; raise max_len")
+        pos = np.full_like(self.positions, -1)
+        pos[rows] = self.positions[rows]
+        # a copy: the CPU backend may alias a numpy argument, and
+        # `self.tokens` changes before this asynchronous call has run
+        logits, self.caches = self._decode(
+            self.params, jnp.asarray(self.tokens.copy()), jnp.asarray(pos),
+            self.caches)
+        self._calls += 1
+        return logits
 
     def prefill_request(self, req: Request):
         """Row prefill: replay the prompt through decode steps (row-isolated
         caches make per-row prefill exact; a production engine would batch
         prefills — see DESIGN.md §serving)."""
         slot = req.slot
+        self.caches = self._reset_row(self.caches, slot)
+        self._born[slot] = self._calls
         for i, tok in enumerate(req.prompt):
             self.tokens[slot, 0] = tok
             self.positions[slot, 0] = i
-            logits, self.caches = self._decode(
-                self.params, jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                self.caches)
+            logits = self._call([slot])
         self.row_pos[slot] = len(req.prompt)
         req._last_logits = np.asarray(logits[slot])
 
     def step_fn(self, active_reqs):
-        for r in active_reqs:
-            slot = r.slot
-            self.tokens[slot, 0] = r.out_tokens[-1] if r.out_tokens else r.prompt[-1]
-            self.positions[slot, 0] = self.row_pos[slot]
-            self.row_pos[slot] += 1
-        logits, self.caches = self._decode(
-            self.params, jnp.asarray(self.tokens), jnp.asarray(self.positions), self.caches)
-        return np.asarray(logits)[[r.slot for r in active_reqs]]
+        """Next-token logits of each request: a request's first token comes
+        from its prompt's logits, every later one from a decode call that
+        feeds its last token."""
+        decoding = [r for r in active_reqs if r.out_tokens]
+        for r in decoding:
+            self.tokens[r.slot, 0] = r.out_tokens[-1]
+            self.positions[r.slot, 0] = self.row_pos[r.slot]
+            self.row_pos[r.slot] += 1
+        if decoding:
+            logits = np.asarray(self._call([r.slot for r in decoding]))
+        return np.stack([logits[r.slot] if r.out_tokens else r._last_logits
+                         for r in active_reqs])
 
 
 def main(argv=None):
@@ -70,7 +114,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    server = ModelServer(args.arch, args.slots)
+    setup_compile_cache()
+    server = ModelServer(get_smoke_config(args.arch), args.slots)
     engine = ContinuousBatchingEngine(
         server.step_fn, server.prefill_request, args.slots)
 

@@ -10,7 +10,6 @@ uniform (see layers.attention_forward).
 from __future__ import annotations
 
 import jax
-from .. import compat
 import jax.numpy as jnp
 
 
@@ -92,7 +91,7 @@ def _ring_write_sharded(cache, k, v, positions, slot, shard_axes):
     cache's sequence-sharding axes, auto elsewhere)."""
     from jax.sharding import PartitionSpec as P
 
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     seq_spec = tuple(shard_axes) if len(shard_axes) > 1 else shard_axes[0]
 
     def body(kc, vc, pc, kn, vn, pn, slot_):
@@ -121,7 +120,7 @@ def _ring_write_sharded(cache, k, v, positions, slot, shard_axes):
     kv_spec = P(None, seq_spec, None, None)
     pos_spec = P(None, seq_spec)
     rep4 = P(None, None, None, None)
-    kc, vc, pc = compat.shard_map(
+    kc, vc, pc = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(kv_spec, kv_spec, pos_spec, rep4, rep4, P(None, None), P()),

@@ -156,6 +156,54 @@ def init_caches(cfg: ModelConfig, batch: int, capacity: int, dtype):
     return caches
 
 
+def _map_block_caches(fn, cfg: ModelConfig, *trees):
+    """``fn(spec, batch_axis, *block_caches)`` over every block of caches
+    laid out as `init_caches` lays them (the unit stack leads with U)."""
+    out = {}
+    if cfg.num_units > 0:
+        out["units"] = {
+            f"b{i}": fn(spec, 1, *(t["units"][f"b{i}"] for t in trees))
+            for i, spec in enumerate(cfg.unit)
+        }
+    for name, blocks in (("prefix", cfg.prefix), ("suffix", cfg.suffix)):
+        if blocks:
+            out[name] = [fn(s, 0, *cs) for s, *cs in zip(blocks, *(t[name] for t in trees))]
+    return out
+
+
+def reset_cache_row(cfg: ModelConfig, caches, row):
+    """``caches`` with batch row ``row`` as `init_caches` made it: KV entries
+    empty (position -1) and recurrent states zero.  The KV ring cursors are
+    shared by every row and kept."""
+
+    def reset(spec, ax, c):
+        at = (slice(None),) * ax + (row,)
+        if spec.kind in ("attn", "moe"):
+            return {**c, "pos": c["pos"].at[at].set(-1)}
+        return jax.tree.map(lambda x: x.at[at].set(0), c)
+
+    return _map_block_caches(reset, cfg, caches)
+
+
+def keep_cache_rows(cfg: ModelConfig, new, old, live):
+    """``new`` (after a decode step from ``old``) with the recurrent states of
+    the rows where ``live`` (B,) is False taken back from ``old``.  A decode
+    step advances every row's recurrent state; a row at position -1 writes
+    only an empty KV entry, so KV caches are taken from ``new`` as they are."""
+
+    def keep(spec, ax, n, o):
+        if spec.kind in ("attn", "moe"):
+            return n
+
+        def pick(a, b):
+            rows = live.reshape((1,) * ax + (-1,) + (1,) * (a.ndim - ax - 1))
+            return jnp.where(rows, a, b)
+
+        return jax.tree.map(pick, n, o)
+
+    return _map_block_caches(keep, cfg, new, old)
+
+
 # --------------------------------------------------------------- forward ----
 
 

@@ -34,7 +34,6 @@ from typing import Any
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 
 # logical name → mesh axis (or tuple), for the canonical 2D/3D meshes
 _DEFAULT_RULES = {
@@ -73,7 +72,7 @@ def current_rules() -> dict:
 
 
 def mesh_axes() -> dict[str, int]:
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or not m.axis_names:
         return {}
     return {name: size for name, size in m.shape_tuple}

@@ -26,7 +26,6 @@ from functools import partial
 from typing import Any, NamedTuple
 
 import jax
-from .. import compat
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
@@ -226,7 +225,7 @@ def make_train_step(cfg: ModelConfig, shape: ShapeSpec, sc: StepConfig,
     def train_step_compressed(state: TrainState, batch):
         axes = mesh_axes()
         n_pods = axes.get("pod", 1)
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         with axis_rules(**rules):
             # grads within each pod: data+model handled automatically (auto
             # axes), pod manual. Batch enters split over pod (dim 0).
@@ -252,7 +251,7 @@ def make_train_step(cfg: ModelConfig, shape: ShapeSpec, sc: StepConfig,
                 metrics = jax.tree.map(lambda m: jax.lax.pmean(m, "pod"), metrics)
                 return grads, residuals, metrics
 
-            grads, new_res, metrics = compat.shard_map(
+            grads, new_res, metrics = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(rep, in_batch_specs, rep),

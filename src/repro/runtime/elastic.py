@@ -24,8 +24,6 @@ from dataclasses import dataclass
 
 import jax
 
-from .. import compat
-
 from ..checkpoint.manager import CheckpointManager
 from ..parallel import steps as steps_lib
 
@@ -61,7 +59,7 @@ def build_mesh(plan: ElasticPlan):
 def reshard_state(state, sc, mesh):
     """Re-device-put a (restored, host-resident) train state with the specs
     of the new mesh."""
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         specs = steps_lib.train_state_pspecs(state, sc)
         flat_s, tdef = jax.tree_util.tree_flatten(state)
         flat_p = tdef.flatten_up_to(specs)

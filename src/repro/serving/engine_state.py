@@ -1215,11 +1215,12 @@ def fused_round_impl(state, tenant_ids, tickets, alive, deadlines, now,
     (`kernels.qos_admission.qos_round_fused`) — bit-identical to the
     functional default; the scheduler selects it on TPU backends where
     the kernel compiles natively inside the scan."""
+    from ..kernels.ops import use_interpret
     from ..kernels.qos_admission import qos_round_fused
 
     return qos_round_fused(state, tenant_ids, tickets, alive, deadlines,
                            now, free_units, max_units=max_units,
-                           interpret=jax.default_backend() != "tpu")
+                           interpret=use_interpret())
 
 
 # --------------------------------------------------------------- models ----

@@ -1614,9 +1614,11 @@ class ContinuousBatchingEngine:
                 if nows_a.shape != (K,):
                     raise ValueError(f"nows must be shape ({K},)")
             if admit_impl == "auto":
+                from ..kernels.ops import use_interpret
+
                 admit_impl = (fused_round_impl
-                              if self._use_kernel
-                              and jax.default_backend() == "tpu" else None)
+                              if self._use_kernel and not use_interpret()
+                              else None)
 
             # donation requires every leaf to own a distinct buffer: the
             # freshly-built state is small (copy unconditionally — fresh

@@ -102,9 +102,9 @@ _MESH_EQUIV_SCRIPT = textwrap.dedent("""
     results["single"] = float(m["loss"])
 
     # 2x4 mesh (dp=2, tp=4) with FSDP
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
-    from repro import compat
-    with compat.set_mesh(mesh):
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
+    with jax.set_mesh(mesh):
         sc2 = steps_lib.default_step_config(cfg, shape, dp=2, accum_steps=2,
                                             param_dtype=jnp.float32, fsdp=True)
         state2 = steps_lib.make_train_state(jax.random.PRNGKey(0), cfg, sc2)
@@ -121,19 +121,9 @@ _MESH_EQUIV_SCRIPT = textwrap.dedent("""
 """)
 
 
-# jax 0.4.x ships the old XLA whose FSDP all-gather + accumulation ordering
-# drifts these two archs ~0.2% in fp32 loss (pre-existing seed reds; current
-# jax passes) — version-gated so tier-1 stays green and REAL regressions on
-# the other archs/newer jax remain visible.
-_OLD_XLA = tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
-_OLD_XLA_DRIFT = pytest.mark.xfail(
-    condition=_OLD_XLA, strict=False,
-    reason="old-XLA (jax<0.5) FSDP accumulation numeric drift, pre-existing")
-
-
 @pytest.mark.parametrize("arch", [
-    pytest.param("qwen2-0.5b", marks=_OLD_XLA_DRIFT),
-    pytest.param("deepseek-moe-16b", marks=_OLD_XLA_DRIFT),
+    "qwen2-0.5b",
+    "deepseek-moe-16b",
     "recurrentgemma-9b",
     "gemma3-1b",
 ])

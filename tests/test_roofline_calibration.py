@@ -21,10 +21,10 @@ _SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro import compat
     from repro.launch.hlo_analysis import analyze_collectives
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8, 8), ("data", "model"))
+    mesh = make_mesh((8, 8), ("data", "model"))
     ns = lambda s: jax.sharding.NamedSharding(mesh, s)
     n = 1024
     x = jax.ShapeDtypeStruct((n, n), jnp.float32)
@@ -35,7 +35,7 @@ _SCRIPT = textwrap.dedent("""
     c = jax.jit(lambda a, b: a @ b,
                 in_shardings=(ns(P("data", None)), ns(P(None, "model")))
                 ).lower(x, w).compile()
-    out["matmul_flops"] = compat.cost_analysis(c)["flops"]
+    out["matmul_flops"] = c.cost_analysis()["flops"]
     out["matmul_expected_per_chip"] = 2 * n**3 / 64
 
     # 2. while-body counted once
@@ -44,7 +44,7 @@ _SCRIPT = textwrap.dedent("""
     c2 = jax.jit(scanned).lower(
         jax.ShapeDtypeStruct((256, 256), jnp.float32),
         jax.ShapeDtypeStruct((256, 256), jnp.float32)).compile()
-    out["scan_flops"] = compat.cost_analysis(c2)["flops"]
+    out["scan_flops"] = c2.cost_analysis()["flops"]
     out["one_body"] = 2 * 256**3
 
     # 3. collective parse: resharding a model-sharded tensor to replicated

@@ -13,6 +13,7 @@ import pytest
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import BoundedBuffer, DataLoader, SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.optim.compression import BLOCK, compress_psum, init_residuals
 from repro.runtime.coordinator import Coordinator, DistributedTicketLease, KVStore
 from repro.serving.scheduler import ContinuousBatchingEngine, Request
@@ -194,16 +195,15 @@ def test_compression_ef_residual_correctness():
     and the residual must carry exactly the quantization error."""
     import jax
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     g = jnp.asarray(np.random.default_rng(0).normal(size=(1000,)), jnp.float32)
     r0 = jnp.zeros_like(g)
 
     def f(g, r):
         return compress_psum(g, r, "pod", 1)
 
-    from repro import compat
     out, res = jax.jit(
-        compat.shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec(),) * 2,
+        jax.shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec(),) * 2,
                          out_specs=(jax.sharding.PartitionSpec(),) * 2)
     )(g, r0)
     np.testing.assert_allclose(np.asarray(out + res), np.asarray(g), atol=1e-5)
@@ -215,14 +215,12 @@ def test_compression_ef_residual_correctness():
 def test_compression_unbiased_over_time():
     """Error feedback: Σ_t compressed_t ≈ Σ_t g_t (noise does not accumulate)."""
     rng = np.random.default_rng(1)
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     P = jax.sharding.PartitionSpec
-
-    from repro import compat
 
     @jax.jit
     def step(g, r):
-        return compat.shard_map(lambda g, r: compress_psum(g, r, "pod", 1),
+        return jax.shard_map(lambda g, r: compress_psum(g, r, "pod", 1),
                                 mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))(g, r)
 
     r = jnp.zeros((512,), jnp.float32)

@@ -1,0 +1,171 @@
+"""Compile-for-chip rehearsals: the served Pallas kernels and a full-width
+qwen2-0.5b decode step, compiled without interpret mode for a described
+TPU v5e chip at the widths `chip_smoke.py` serves.  Nothing runs, so these
+say nothing about results or times; they catch what only the chip's
+compiler refuses (Mosaic layouts, unsupported ops, memory) at no chip
+time.  The topology is described inside a fixture (never at import), and
+the persistent compilation cache is off while these compile: an entry
+written for a described chip cannot be read back without one."""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(sharding, *specs):
+    return [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in specs]
+
+
+def _qos(sharding):
+    from repro.admission.functional_qos import make_qos
+    from repro.kernels.qos_admission import qos_round_fused
+
+    # the megastep's admission round: 4 tenants, a 1024-row backlog,
+    # 64 slots, the engine's 256-bucket waiting array
+    N = 1024
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        make_qos([4.0, 2.0, 1.0, 1.0], table_size=256))
+    fn = lambda st, *a: qos_round_fused(st, *a, max_units=64)
+    return fn, [state] + _shapes(
+        sharding, ((N,), jnp.int32), ((N,), jnp.uint32), ((N,), jnp.bool_),
+        ((N,), jnp.float32), ((), jnp.float32), ((), jnp.int32))
+
+
+def _sema(sharding):
+    from repro.kernels.sema_batch import sema_batch
+
+    u = ((), jnp.uint32)
+    return sema_batch, _shapes(sharding, u, u, ((256,), jnp.uint32),
+                               ((1024,), jnp.bool_), u, u)
+
+
+# qwen2-0.5b attention geometry over the served pool: 64 slots, a
+# 4096-block pool of 16-token blocks, 16-block tables, 32-token chunks
+S, H, KV, HD, NB, BS, MB, CT = 64, 14, 2, 64, 4096, 16, 16, 32
+POOL = ((NB, BS, KV, HD), jnp.bfloat16)
+
+
+def _decode(sharding):
+    from repro.kernels.paged_decode import paged_decode
+
+    return paged_decode, _shapes(sharding, ((S, H, HD), jnp.bfloat16), POOL,
+                                 POOL, ((S, MB), jnp.int32), ((S,), jnp.int32))
+
+
+def _prefill(sharding):
+    from repro.kernels.paged_prefill import paged_prefill
+
+    return paged_prefill, _shapes(
+        sharding, ((S, CT, H, HD), jnp.bfloat16),
+        ((S, CT, KV, HD), jnp.bfloat16), ((S, CT, KV, HD), jnp.bfloat16),
+        POOL, POOL, ((S, MB), jnp.int32), ((S,), jnp.int32),
+        ((S,), jnp.int32))
+
+
+@pytest.mark.parametrize("build", [_qos, _sema, _decode, _prefill],
+                         ids=["qos_round_fused", "sema_batch",
+                              "paged_decode", "paged_prefill"])
+def test_kernel_compiles_for_v5e(chip, build):
+    fn, args = build(chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < HBM_BYTES
+
+
+def test_qwen2_decode_step_fits_v5e(chip):
+    """Full-width qwen2-0.5b bf16 decode step at the width `chip_smoke.py`
+    serves (4 slots, 384-entry KV ring)."""
+    from repro.configs.registry import get_config
+    from repro.models.transformer import decode_step, init_caches, init_params
+
+    cfg = get_config("qwen2-0.5b")
+    place = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), t)
+    params = place(jax.eval_shape(
+        lambda k: init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(
+        lambda: init_caches(cfg, 4, 384, jnp.bfloat16)))
+    tok, pos = _shapes(chip, ((4, 1), jnp.int32), ((4, 1), jnp.int32))
+    compiled = jax.jit(lambda p, t, q, c: decode_step(p, cfg, t, q, c)
+                       ).lower(params, tok, pos, caches).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 0.9e9 < m.argument_size_in_bytes  # ~494M bf16 weights
+    assert total < HBM_BYTES, total
+
+
+def test_served_megastep_compiles_for_v5e(chip, monkeypatch):
+    """The served megastep — four weighted tenants, the fused admission
+    kernel, a 4096-block pool, chunked prefill and the prefix cache — at
+    `chip_smoke.py`'s widths (64 slots, a 1024-row backlog).  The engine
+    prepares its launch arguments on the CPU; their shapes are then
+    compiled for the described chip with the kernel native."""
+    import numpy as np
+
+    import repro.kernels.ops as ops
+    from repro.serving import engine_state
+    from repro.serving.scheduler import ContinuousBatchingEngine, Request
+
+    jitted = engine_state.megastep_jit
+    seen = []
+
+    class Launched(Exception):
+        pass
+
+    def capture(*args, **static):
+        seen.append(jitted.lower(*jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+            args), **static).compile())
+        raise Launched
+
+    monkeypatch.setattr(engine_state, "megastep_jit", capture)
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    eng = ContinuousBatchingEngine(
+        None, None, 64, tenants={"a": 4.0, "b": 2.0, "c": 1.0, "d": 1.0},
+        use_kernel=True, kv_pool=(4096, 16, 16), prompt_cap=128,
+        chunked_prefill=(32, 512), prefix_cache=1024, watchdog=64)
+    eng.megastep_model = engine_state.make_paged_pool_model(
+        jax.random.PRNGKey(0), vocab=32000, d=128, num_blocks=4096,
+        block_size=16)
+    rng = np.random.default_rng(0)
+    eng.submit_batch([
+        Request(rid=i, prompt=[int(t) for t in rng.integers(1, 32000, 100)],
+                max_new_tokens=16, tenant_id="abcd"[i % 4])
+        for i in range(1024)])
+    with pytest.raises(Launched):
+        eng.megastep(32, token_fn=engine_state.make_chunked_prefill_token_fn(
+            32), admit_impl=engine_state.fused_round_impl)
+    (compiled,) = seen
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes) < HBM_BYTES
