@@ -25,6 +25,7 @@ from ..models.transformer import (
     keep_cache_rows,
     reset_cache_row,
 )
+from ..obs.trace import count, span
 from ..serving.scheduler import ContinuousBatchingEngine, Request
 from .compile_cache import setup_compile_cache
 
@@ -78,30 +79,52 @@ class ModelServer:
     def prefill_request(self, req: Request):
         """Row prefill: replay the prompt through decode steps (row-isolated
         caches make per-row prefill exact; a production engine would batch
-        prefills — see DESIGN.md §serving)."""
+        prefills — see DESIGN.md §serving).  Phase spans (under a profiler
+        session): ``serve.prefill`` around ``serve.reset``, ``serve.replay``
+        (the host's side of the per-token calls) and ``serve.prompt_logits``
+        (the wait for them and the copy of the last row's logits)."""
         slot = req.slot
-        self.caches = self._reset_row(self.caches, slot)
-        self._born[slot] = self._calls
-        for i, tok in enumerate(req.prompt):
-            self.tokens[slot, 0] = tok
-            self.positions[slot, 0] = i
-            logits = self._call([slot])
-        self.row_pos[slot] = len(req.prompt)
-        req._last_logits = np.asarray(logits[slot])
+        n = len(req.prompt)
+        with span("serve.prefill", n):
+            with span("serve.reset"):
+                self.caches = self._reset_row(self.caches, slot)
+            self._born[slot] = self._calls
+            with span("serve.replay", n):
+                for i, tok in enumerate(req.prompt):
+                    self.tokens[slot, 0] = tok
+                    self.positions[slot, 0] = i
+                    logits = self._call([slot])
+            self.row_pos[slot] = n
+            with span("serve.prompt_logits"):
+                count("d2h")
+                req._last_logits = np.asarray(logits[slot])
 
     def step_fn(self, active_reqs):
         """Next-token logits of each request: a request's first token comes
         from its prompt's logits, every later one from a decode call that
-        feeds its last token."""
+        feeds its last token.  Phase spans (under a profiler session):
+        ``serve.decode`` (``n``: rows decoded) around ``serve.feed`` (the
+        inputs, their copies to the device and the dispatch),
+        ``serve.wait`` (until the logits are ready) and ``serve.fetch``
+        (their copy to the host and the gather; ``n`` as above)."""
         decoding = [r for r in active_reqs if r.out_tokens]
-        for r in decoding:
-            self.tokens[r.slot, 0] = r.out_tokens[-1]
-            self.positions[r.slot, 0] = self.row_pos[r.slot]
-            self.row_pos[r.slot] += 1
-        if decoding:
-            logits = np.asarray(self._call([r.slot for r in decoding]))
-        return np.stack([logits[r.slot] if r.out_tokens else r._last_logits
-                         for r in active_reqs])
+        n = len(decoding)
+        with span("serve.decode", n):
+            if decoding:
+                with span("serve.feed"):
+                    for r in decoding:
+                        self.tokens[r.slot, 0] = r.out_tokens[-1]
+                        self.positions[r.slot, 0] = self.row_pos[r.slot]
+                        self.row_pos[r.slot] += 1
+                    logits = self._call([r.slot for r in decoding])
+                with span("serve.wait"):
+                    logits.block_until_ready()
+            with span("serve.fetch", n):
+                if decoding:
+                    count("d2h")
+                    logits = np.asarray(logits)
+                return np.stack([logits[r.slot] if r.out_tokens
+                                 else r._last_logits for r in active_reqs])
 
 
 def main(argv=None):
@@ -138,7 +161,6 @@ def main(argv=None):
     dt = time.time() - t0
     tel = engine.telemetry()
     tok = sum(len(r.out_tokens) for r in reqs)
-    waits = [r.admit_t - r.enqueue_t for r in reqs]
     order_ok = all(
         reqs[i].admit_t <= reqs[j].admit_t + 1e-6
         for i in range(len(reqs)) for j in range(i + 1, len(reqs))

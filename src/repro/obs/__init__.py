@@ -20,14 +20,20 @@ sample stream:
 * :class:`TraceBuffer` / :func:`build_spans` / :func:`to_perfetto`
   (PR 10) — per-request span trees from the in-scan event table, with
   Chrome-trace export and critical-path breakdowns;
+* :func:`span` / :func:`count` / :func:`phase_records` — phase spans and
+  counts inside the engine's ``step`` and ``ModelServer``, recorded only
+  while a JAX profiler session runs, on ``time.perf_counter_ns`` and as
+  ``repro.*`` annotations in the profiler's trace (`trace.py`);
 * :class:`FlightRecorder` (PR 10) — bounded pre-crash window that cuts a
   post-mortem bundle on sentinel trips, recovery-ladder engagement, or a
   replica reap;
 * :func:`aggregate` (PR 10) — cross-replica ``EngineObs`` reduction to
   fleet-level p50/p99/p999 TTFT/TPOT and per-replica health.
 
-Everything here is plain Python/numpy — no jax imports, no device work:
-attaching an ``EngineObs`` never adds a host sync to either serving path.
+Everything here is plain Python/numpy with no device work (`trace.py`
+imports only ``jax.profiler``, for the phase spans' gate and
+annotations): attaching an ``EngineObs`` never adds a host sync to either
+serving path.
 """
 
 from .cluster import aggregate, render_cluster_table
@@ -37,7 +43,8 @@ from .recorder import EngineObs
 from .sinks import CallbackSink, JsonlSink, StdoutSink
 from .slo import TenantSLO
 from .smooth import RollingMedian
-from .trace import TraceBuffer, build_spans, to_perfetto, write_perfetto
+from .trace import (PHASES, PhaseLog, TraceBuffer, build_spans, count,
+                    phase_records, span, to_perfetto, write_perfetto)
 
 __all__ = [
     "LogHistogram",
@@ -51,6 +58,11 @@ __all__ = [
     "build_spans",
     "to_perfetto",
     "write_perfetto",
+    "PhaseLog",
+    "PHASES",
+    "span",
+    "count",
+    "phase_records",
     "FlightRecorder",
     "aggregate",
     "render_cluster_table",

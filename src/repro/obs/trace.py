@@ -1,16 +1,18 @@
-"""Request tracing: event buffer, span builder, Perfetto export (PR 10).
+"""Request tracing and phase spans: two records of where time goes.
 
-The device emits a fixed-shape per-round event table inside the scanned
-engine round (`serving.engine_state`); the host ``step()`` mirrors the
-identical records.  Either way the drained stream is a flat list of
+**Request events** follow each request through its lifecycle on
+the engine's injectable virtual clock.  The device emits a fixed-shape
+per-round event table inside the scanned engine round
+(`serving.engine_state`); the host ``step()`` mirrors the identical
+records.  Either way the drained stream is a flat list of
 ``(kind, uid, slot, arg)`` tuples stamped with the round's virtual clock.
 This module turns that stream into something a human (or chrome://tracing)
 can read:
 
 * :class:`TraceBuffer` — a bounded event log fed by the scheduler and the
   router.  ``ingest_sample`` drains one telemetry sample's event list;
-  ``add`` appends a single host-side (fabric) event.  Plain Python, no
-  jax: attaching a buffer adds ZERO host syncs.
+  ``add`` appends a single host-side (fabric) event.  Plain Python:
+  attaching a buffer adds ZERO host syncs.
 * :func:`build_spans` — per-request span trees keyed by uid.  A span
   survives migration (several ADMIT episodes on different replicas) and
   first-completion-wins dedupe (later duplicate terminals are counted,
@@ -29,20 +31,39 @@ Critical-path breakdown per request::
 
 All times are on the engine's virtual clock, so breakdowns are exactly
 reproducible and identical between the host loop and megastep paths.
+
+**Phase spans** name the host's work inside one serving call — admission,
+sampling, the model's feed, wait and fetch — on real clocks, so that an
+idle gap in a device trace can be matched to what the host was doing.
+:func:`span` and :func:`count` record only while a JAX profiler session
+runs (``jax.profiler.TraceAnnotation.is_enabled()``); otherwise ``span``
+returns a shared no-op and ``count`` returns at once.  A span then lands
+twice: as a ``repro.<name>`` annotation in the profiler's own trace (on
+the device trace's clock; Perfetto opens it), and as a record
+``(name, t0_ns, t1_ns, parent, n)`` in the bounded :data:`PHASES` log on
+`time.perf_counter_ns`.  A count is a record of zero length whose ``n``
+is the amount counted.  :func:`phase_records` reads what was recorded
+between two `time.perf_counter_ns` stamps.  Nothing is written to disk.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
+import threading
+import time
 from collections import deque
 from typing import Any, Iterable, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..serving.events import (EVENT_NAMES, EV_ADMIT, EV_EXPIRE, EV_FINISH,
                               EV_MIGRATE, EV_PARK, EV_PREEMPT,
                               EV_PREFILL_CHUNK, EV_RESUME, EV_SHED,
                               EV_SUBMIT, TERMINAL_EVENTS)
 
-__all__ = ["TraceBuffer", "build_spans", "to_perfetto", "write_perfetto"]
+__all__ = ["TraceBuffer", "build_spans", "to_perfetto", "write_perfetto",
+           "PhaseLog", "PHASES", "span", "count", "phase_records"]
 
 
 class TraceBuffer:
@@ -301,3 +322,100 @@ def write_perfetto(path: str, spans: dict[int, dict], *,
     with open(path, "w") as f:
         json.dump(to_perfetto(spans, time_scale=time_scale), f)
     return path
+
+
+# ------------------------------------------------------------ phase spans ----
+
+
+class PhaseLog:
+    """Bounded log of phase records ``(name, t0_ns, t1_ns, parent, n)`` on
+    `time.perf_counter_ns`: ``parent`` is the name of the span open around
+    it on the same thread (None at the top), ``n`` what the caller counted
+    (tokens, rows; for a count, the amount).  Once full, the OLDEST records
+    are dropped and counted in ``dropped``."""
+
+    def __init__(self, capacity: int = 65536):
+        self._recs: deque[tuple] = deque(maxlen=capacity)
+        self.total = 0  # records ever appended (incl. dropped)
+        self._lock = threading.Lock()
+        self._local = threading.local()  # per-thread stack of open spans
+
+    def __len__(self) -> int:
+        return len(self._recs)
+
+    @property
+    def dropped(self) -> int:
+        return self.total - len(self._recs)
+
+    def _stack(self) -> list[str]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def append(self, rec: tuple) -> None:
+        with self._lock:
+            self._recs.append(rec)
+            self.total += 1
+
+    def span(self, name: str, n: int = 0) -> "_Span":
+        return _Span(self, name, n)
+
+    def count(self, name: str, k: int = 1) -> None:
+        stack = self._stack()
+        t = time.perf_counter_ns()
+        self.append((name, t, t, stack[-1] if stack else None, k))
+
+    def records(self, lo_ns: int, hi_ns: int) -> list[tuple]:
+        """The records that lie wholly inside ``[lo_ns, hi_ns]``."""
+        return [r for r in list(self._recs) if r[1] >= lo_ns and r[2] <= hi_ns]
+
+
+class _Span:
+    __slots__ = ("log", "name", "n", "parent", "anno", "t0")
+
+    def __init__(self, log: PhaseLog, name: str, n: int):
+        self.log, self.name, self.n = log, name, n
+
+    def __enter__(self) -> "_Span":
+        stack = self.log._stack()
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self.anno = TraceAnnotation("repro." + self.name)
+        self.anno.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self.anno.__exit__(*exc)
+        self.log._stack().pop()
+        self.log.append((self.name, self.t0, t1, self.parent, self.n))
+
+
+_OFF = contextlib.nullcontext()
+# One log per process, as the profiler session that gates it is one per
+# process: a benchmark reads what the engine and the model server recorded
+# without either being handed to it.
+PHASES = PhaseLog()
+_profiling = TraceAnnotation.is_enabled
+
+
+def span(name: str, n: int = 0):
+    """Context manager timing one phase named ``name`` (``n``: what it
+    processes, e.g. prompt tokens) while a profiler session runs."""
+    return PHASES.span(name, n) if _profiling() else _OFF
+
+
+def count(name: str, k: int = 1) -> None:
+    """Count ``k`` occurrences of ``name`` (``"d2h"``: blocking
+    device→host readbacks) while a profiler session runs."""
+    if _profiling():
+        PHASES.count(name, k)
+
+
+def phase_records(lo_ns: int, hi_ns: int) -> list[tuple]:
+    """:data:`PHASES`' records wholly inside ``[lo_ns, hi_ns]``, two
+    `time.perf_counter_ns` stamps."""
+    return PHASES.records(lo_ns, hi_ns)

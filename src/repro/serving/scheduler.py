@@ -78,6 +78,7 @@ from ..core.functional import (
     woken_mask,
 )
 from ..core.twa_semaphore import TWASemaphore
+from ..obs.trace import count, span
 from .events import (
     EV_ADMIT,
     EV_COW,
@@ -116,9 +117,7 @@ class Request:
     preempted: bool = False  # deadline passed mid-decode (slot reclaimed)
     out_tokens: list[int] = field(default_factory=list)
     done_event: threading.Event = field(default_factory=threading.Event)
-    enqueue_t: float = 0.0
     admit_t: float = 0.0
-    finish_t: float = 0.0
     # --- virtual-clock lifecycle stamps (repro.obs SLO tracking) ---
     # Keyed on the injectable ``clock=`` (NOT wall time), so TTFT/TPOT are
     # reproducible under virtual time and bit-identical between the host
@@ -160,7 +159,9 @@ class EngineStats:
     backlog_scans: int = 0  # requests re-examined by the scheduler loop
     backlog_skipped: int = 0  # requests NOT re-examined thanks to TWA buckets
     wakeups: int = 0
-    host_syncs: int = 0  # host↔device round-trips (1/step; 1/megastep)
+    host_syncs: int = 0  # host rounds: 1 per step(), 1 per megastep();
+    #                      not transfers (a step() blocks on several
+    #                      device→host readbacks: the "d2h" phase count)
     kv_block_stalls: int = 0  # cumulative parked slot-rounds (block waits)
     prefill_chunks: int = 0  # prompt chunks written (chunked prefill)
     prefix_hits: int = 0  # admissions whose whole prompt was cache-covered
@@ -394,7 +395,6 @@ class ContinuousBatchingEngine:
         if self._tenants is not None:
             self._submit_qos([req])
             return req
-        req.enqueue_t = time.time()
         with self._lock:
             req.submit_clock = self._clock()
             self._sema_ticket_h += 1
@@ -432,7 +432,6 @@ class ContinuousBatchingEngine:
             else:
                 self.sema, tk, adm, bkt = take_batch(self.sema, jnp.ones((n,), bool))
             for r, t, b, a in zip(reqs, np.asarray(tk), np.asarray(bkt), np.asarray(adm)):
-                r.enqueue_t = time.time()
                 r.submit_clock = sclk
                 r.ticket = int(t)
                 r.bucket = int(b)
@@ -520,7 +519,6 @@ class ContinuousBatchingEngine:
             seq = np.asarray(self.qos.bucket_seq)
             for r, i, t, b, e in zip(reqs, ids, np.asarray(tickets),
                                      np.asarray(buckets), np.asarray(expired)):
-                r.enqueue_t = time.time()
                 r.submit_clock = now
                 self._trace.add(EV_SUBMIT, r.rid, -1, 0, now,
                                 self._round_no)
@@ -757,6 +755,7 @@ class ContinuousBatchingEngine:
         before its predecessor).  Cross-tenant ordering is cosmetic — FCFS
         is a per-tenant invariant.  The grant snapshot is taken ONCE (one
         device→host transfer per round, not per request)."""
+        count("d2h")
         grants = np.asarray(self.qos.grant)
 
         def key(r: Request):
@@ -771,7 +770,6 @@ class ContinuousBatchingEngine:
         r.expire_round = self._round_no  # round; host paths use this one
         self.stats.expired += 1
         self.tenant_expired[self._tenant_names[tidx]] += 1
-        r.finish_t = time.time()
         if r.finish_clock is None:  # megastep drains pre-stamp per-round
             r.finish_clock = self._clock()
         # backlog expiry is host-resolved on BOTH serving paths (heap pop
@@ -829,6 +827,7 @@ class ContinuousBatchingEngine:
             self.qos, ids, tks, np.ones(len(rows), bool), dls, 0.0,
             self._qos_free, max_units=self.n_slots)
         self.qos = state
+        count("d2h", 3)  # leftover, admitted and expired, read back here
         self._qos_free = int(leftover)
         self.stats.backlog_scans += len(rows)
         admitted = np.asarray(admitted).copy()
@@ -1001,7 +1000,6 @@ class ContinuousBatchingEngine:
         the sequence is tombstoned (expired mid-decode), not completed —
         same slot-release path, different accounting."""
         req = self.active.pop(slot)
-        req.finish_t = time.time()
         req.finish_clock = self._now_r
         self.free_slots.append(slot)
         if reason == "deadline":
@@ -1081,7 +1079,15 @@ class ContinuousBatchingEngine:
 
     def step(self, sample_fn: Callable[[np.ndarray], np.ndarray]) -> int:
         """One engine iteration: preempt expired → admit → prefill admitted
-        → decode active.  Returns number of active rows."""
+        → decode active.  Returns number of active rows.  Under a profiler
+        session its phases are timed (`repro.obs.trace.span`): ``sched.step``
+        around ``sched.admit``, the prefills, the decode call,
+        ``sched.nonfinite``, ``sched.sample``, ``sched.finish`` and
+        ``sched.telemetry``."""
+        with span("sched.step"):
+            return self._step(sample_fn)
+
+    def _step(self, sample_fn: Callable[[np.ndarray], np.ndarray]) -> int:
         if self._kv_pool is not None and self._kv_state is not None:
             # the device block pool already tracks reservations the host
             # counter can't see — a host admission here would double-book
@@ -1109,33 +1115,35 @@ class ContinuousBatchingEngine:
             a0, e0, p0 = (self.stats.admitted, self.stats.expired,
                           self.stats.preempted)
             self._preempt_expired()
-            admitted = self._admit_ready()
-            if admitted:
-                # mirror the device `_assign_slots` in EVERY mode: admits
-                # in packed-FCFS-key order take ASCENDING free slots.
-                # Under sharing this is load-bearing for block identities
-                # (a slot's take pulls ids off the free queue in slot
-                # order); in the counter-only modes it pins the ADMIT
-                # trace events' slot column to the in-scan event table
-                # bit-exactly (tests/test_obs.py).
-                from .engine_state import _D_CLAMP, _T_BITS
+            with span("sched.admit"):
+                admitted = self._admit_ready()
+                if admitted:
+                    # mirror the device `_assign_slots` in EVERY mode: admits
+                    # in packed-FCFS-key order take ASCENDING free slots.
+                    # Under sharing this is load-bearing for block identities
+                    # (a slot's take pulls ids off the free queue in slot
+                    # order); in the counter-only modes it pins the ADMIT
+                    # trace events' slot column to the in-scan event table
+                    # bit-exactly (tests/test_obs.py).
+                    from .engine_state import _D_CLAMP, _T_BITS
 
-                if self._tenants is not None:
-                    grants = np.asarray(self.qos.grant)
-                else:
-                    grants = None
-                    g0 = int(self.sema.grant)
-                for r in admitted:
-                    tidx = (self._tindex[r.tenant_id]
-                            if grants is not None else 0)
-                    d = (r.ticket
-                         - (int(grants[tidx]) if grants is not None
-                            else g0)) & 0xFFFFFFFF
-                    d = d - (1 << 32) if d >= (1 << 31) else d
-                    r.prio_key = (max(-_D_CLAMP, min(_D_CLAMP, d))
-                                  << _T_BITS) + tidx
-                admitted = sorted(admitted, key=lambda r: r.prio_key)
-                self.free_slots.sort(reverse=True)
+                    if self._tenants is not None:
+                        count("d2h")
+                        grants = np.asarray(self.qos.grant)
+                    else:
+                        grants = None
+                        g0 = int(self.sema.grant)
+                    for r in admitted:
+                        tidx = (self._tindex[r.tenant_id]
+                                if grants is not None else 0)
+                        d = (r.ticket
+                             - (int(grants[tidx]) if grants is not None
+                                else g0)) & 0xFFFFFFFF
+                        d = d - (1 << 32) if d >= (1 << 31) else d
+                        r.prio_key = (max(-_D_CLAMP, min(_D_CLAMP, d))
+                                      << _T_BITS) + tidx
+                    admitted = sorted(admitted, key=lambda r: r.prio_key)
+                    self.free_slots.sort(reverse=True)
             for req in admitted:
                 slot = self.free_slots.pop()
                 req.slot = slot
@@ -1186,8 +1194,9 @@ class ContinuousBatchingEngine:
 
             if not self.active:
                 self._round_no = rnd + 1
-                self._record_round(self._host_sample(rnd, now_r, a0, e0,
-                                                     p0, 0))
+                with span("sched.telemetry"):
+                    self._record_round(self._host_sample(rnd, now_r, a0, e0,
+                                                         p0, 0))
                 return 0
             self.stats.steps += 1
             if self._chunk:
@@ -1197,38 +1206,42 @@ class ContinuousBatchingEngine:
                 decode = list(self.active.items())
             if decode:
                 logits = self.step_fn([r for _, r in decode])
-                if (isinstance(logits, np.ndarray)
-                        and logits.dtype.kind == "f"
-                        and not np.all(np.isfinite(logits))):
-                    self._round_nonfinite = True  # H_NAN sentinel input
-                next_tokens = sample_fn(logits)
-                done_slots = []
-                for (slot, req), tok in zip(decode, next_tokens):
-                    req.out_tokens.append(int(tok))
-                    req.last_adv_round = rnd  # progress re-arms watchdog
-                    if req.first_tok_clock is None:
-                        req.first_tok_clock = now_r
-                    req.last_tok_clock = now_r
-                    if len(req.out_tokens) >= req.max_new_tokens:
-                        done_slots.append(slot)
-                for slot in sorted(done_slots):  # device fin mask: lane-
-                    r = self.active[slot]        # ascending event order
-                    self._ev_finish.append(
-                        [EV_FINISH, r.rid, slot, len(r.out_tokens)])
-                if self._kv_share:
-                    # ONE batched decref for the whole finish phase (the
-                    # device round's completion release) before the
-                    # per-slot retirement bookkeeping
-                    self._hshare_release(done_slots)
+                with span("sched.nonfinite"):
+                    if (isinstance(logits, np.ndarray)
+                            and logits.dtype.kind == "f"
+                            and not np.all(np.isfinite(logits))):
+                        self._round_nonfinite = True  # H_NAN sentinel input
+                with span("sched.sample"):
+                    next_tokens = sample_fn(logits)
+                with span("sched.finish"):
+                    done_slots = []
+                    for (slot, req), tok in zip(decode, next_tokens):
+                        req.out_tokens.append(int(tok))
+                        req.last_adv_round = rnd  # progress re-arms watchdog
+                        if req.first_tok_clock is None:
+                            req.first_tok_clock = now_r
+                        req.last_tok_clock = now_r
+                        if len(req.out_tokens) >= req.max_new_tokens:
+                            done_slots.append(slot)
+                    for slot in sorted(done_slots):  # device fin mask: lane-
+                        r = self.active[slot]        # ascending event order
+                        self._ev_finish.append(
+                            [EV_FINISH, r.rid, slot, len(r.out_tokens)])
+                    if self._kv_share:
+                        # ONE batched decref for the whole finish phase (the
+                        # device round's completion release) before the
+                        # per-slot retirement bookkeeping
+                        self._hshare_release(done_slots)
+                        for slot in done_slots:
+                            self.active[slot].kv_blocks = 0
                     for slot in done_slots:
-                        self.active[slot].kv_blocks = 0
-                for slot in done_slots:
-                    self._finish(slot, "length")
+                        self._finish(slot, "length")
             self.stats.prefix_hits += self._round_prefix_hits
             self.stats.cow_copies += self._round_cow_copies
             self._round_no = rnd + 1
-            self._record_round(self._host_sample(rnd, now_r, a0, e0, p0,
-                                                 len(decode)))
+            with span("sched.telemetry"):
+                self._record_round(self._host_sample(rnd, now_r, a0, e0, p0,
+                                                     len(decode)))
             return len(self.active)
 
     def _chunk_step(self) -> np.ndarray:
@@ -1632,19 +1645,20 @@ class ContinuousBatchingEngine:
             if model is not getattr(self, "_megastep_model_last", None):
                 model = jax.tree_util.tree_map(
                     lambda x: jnp.array(x, copy=True), model)
-            st, model, ys = megastep_jit(
-                state, model, jnp.asarray(nows_a), token_fn=token_fn,
-                admit_fn=admit_fn, admit_impl=admit_impl,
-                block_size=self._kv_bs if paged else 0,
-                chunk=self._chunk if paged else 0,
-                budget=self._budget if paged else 0,
-                commit=self._kv_commit if paged else 0,
-                watchdog=self._watchdog)
-            self.megastep_model = model
-            self._megastep_model_last = model
+            with span("sched.megastep"):
+                st, model, ys = megastep_jit(
+                    state, model, jnp.asarray(nows_a), token_fn=token_fn,
+                    admit_fn=admit_fn, admit_impl=admit_impl,
+                    block_size=self._kv_bs if paged else 0,
+                    chunk=self._chunk if paged else 0,
+                    budget=self._budget if paged else 0,
+                    commit=self._kv_commit if paged else 0,
+                    watchdog=self._watchdog)
+                self.megastep_model = model
+                self._megastep_model_last = model
 
-            # ---- the ONE host sync: drain state + event buffers --------
-            st_h, ys_h = jax.device_get((st, ys))
+                # ---- the ONE host sync: drain state + event buffers ----
+                st_h, ys_h = jax.device_get((st, ys))
             prev_active = dict(self.active)
 
             def req_of(row: int) -> Request:
@@ -1684,7 +1698,6 @@ class ContinuousBatchingEngine:
                     r.expired = True
                     r.preempted = True
                     r.expire_round = base + k
-                    r.finish_t = time.time()
                     r.finish_clock = tk
                     self.stats.preempted += 1
                     self.stats.expired += 1
@@ -1701,7 +1714,6 @@ class ContinuousBatchingEngine:
                     r.last_tok_clock = tk
                 for s in np.flatnonzero(ys_h.fin[k]):
                     r = req_of(int(ys_h.row[k][s]))
-                    r.finish_t = time.time()
                     r.finish_clock = tk
                     self.stats.finished += 1
                     self.stats.wakeups += 1
@@ -2032,6 +2044,7 @@ class ContinuousBatchingEngine:
         if self._tenants is not None:
             # wrap-safe per-tenant credit: u32 difference re-read as i32
             # (the _sdist of core.functional — value survives 2³² wrap)
+            count("d2h", 3)  # grant, consumed and dead
             credit = (np.asarray(self.qos.grant)
                       - np.asarray(self.qos.consumed)).view(np.int32)
             dead = np.asarray(self.qos.dead)
@@ -2121,9 +2134,13 @@ class ContinuousBatchingEngine:
 
         * **No hidden host syncs.**  Every gauge comes off host bookkeeping
           (the counter mirrors) — calling ``telemetry()`` never transfers
-          device arrays and never bumps ``stats.host_syncs``; the per-round
-          sample streams (`last_samples`, the megastep TelemetryRing drain)
-          ride the serving paths' own single sync.
+          device arrays and never bumps ``stats.host_syncs``, which counts
+          host rounds (one per ``step()``, one per ``megastep()``), not
+          transfers.  The megastep's samples ride its one drain; a host
+          ``step()`` builds its sample from three QoS arrays read back from
+          the device, among the several blocking device→host readbacks of
+          a step that the ``"d2h"`` phase count records
+          (`repro.obs.trace.count`, under a profiler session).
         * **``pool_utilization`` is ALWAYS present**: a float in [0, 1]
           (blocks actually holding tokens / pool) for block-paged engines,
           and exactly ``None`` for dense engines — callers branch on the

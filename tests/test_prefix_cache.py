@@ -336,7 +336,7 @@ def test_fully_covered_admit_skips_prefill_entirely():
         clk[0] = k * DT
         eng.step(_IDENT)
         tokens_seen.append(eng._last_samples[-1]["prefill_tokens"])
-        if second.finish_t:
+        if second.done_event.is_set():
             break
     assert len(second.out_tokens) == 2
     assert second.prefill_pos >= 10
