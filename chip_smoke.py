@@ -20,8 +20,9 @@ exits non-zero without its final line.
   functional admission path, and the two must agree request for request.
 * model    — full-width qwen2-0.5b in bfloat16 served by `launch.serve`'s
   `ModelServer` through `ContinuousBatchingEngine.step`; each request's
-  token-by-token prompt replay must match the model's own `prefill` of the
-  same prompt within a bfloat16 tolerance, and every logit must be finite.
+  prompt logits from the server's chunked prefill must match the model's
+  own `prefill` of the same prompt within a bfloat16 tolerance, and every
+  logit must be finite.
 
 The last line of standard output is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}}``.
@@ -48,7 +49,7 @@ N_REQ, N_SLOTS, N_BLOCKS, BLOCK, TABLE, CHUNK = 1024, 64, 4096, 16, 16, 32
 N_REF = 64  # requests drained by both admission paths
 VOCAB, K = 32000, 32  # the pool model's vocabulary; rounds per megastep
 # full-width qwen2-0.5b in bfloat16: 8 requests of 32 prompt tokens and 16
-# new ones over 4 slots; replay vs prefill relative L2 at most MODEL_TOL
+# new ones over 4 slots; served vs prefill relative L2 at most MODEL_TOL
 MODEL, M_REQ, M_SLOTS, M_PROMPT, M_NEW = "qwen2-0.5b", 8, 4, 32, 16
 MODEL_TOL = 5e-2
 
@@ -341,7 +342,7 @@ def model_phase(seed: int) -> dict:
 
     cfg, dtype = get_config(MODEL), jnp.bfloat16
     n_req, n_slots, prompt_len, max_new = M_REQ, M_SLOTS, M_PROMPT, M_NEW
-    # the KV ring advances once per decode call (prompt replays included),
+    # the KV ring advances once per decode call and once per prompt token,
     # so size it for every call of the run: it never wraps
     ring = n_req * (prompt_len + max_new)
     server = ModelServer(cfg, n_slots, max_len=ring, seed=seed, dtype=dtype)
@@ -378,7 +379,7 @@ def model_phase(seed: int) -> dict:
     assert np.isfinite(got).all() and np.isfinite(want).all()
     rel = (np.linalg.norm(got - want, axis=1)
            / np.maximum(np.linalg.norm(want, axis=1), 1e-30))
-    assert rel.max() <= MODEL_TOL, f"replay vs prefill relative L2 {rel.max()}"
+    assert rel.max() <= MODEL_TOL, f"served vs prefill relative L2 {rel.max()}"
     return {
         "model": cfg.name,
         "params": int(sum(x.size for x in jax.tree.leaves(server.params))),
@@ -386,7 +387,7 @@ def model_phase(seed: int) -> dict:
         "tokens": sum(len(r.out_tokens) for r in reqs),
         "engine_steps": steps,
         "serve_seconds": wall,
-        "replay_vs_prefill_rel_l2": float(rel.max()),
+        "served_vs_prefill_rel_l2": float(rel.max()),
         "argmax_agree": int((got.argmax(1) == want.argmax(1)).sum()),
     }
 

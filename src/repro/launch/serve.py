@@ -23,11 +23,14 @@ from ..models.transformer import (
     init_caches,
     init_params,
     keep_cache_rows,
+    prefill_chunk,
     reset_cache_row,
 )
 from ..obs.trace import count, span
 from ..serving.scheduler import ContinuousBatchingEngine, Request
 from .compile_cache import setup_compile_cache
+
+PREFILL_CHUNK = 256  # prompt tokens per prefill call (at most max_len)
 
 
 class ModelServer:
@@ -39,7 +42,8 @@ class ModelServer:
     write an empty entry (position -1) and keep their recurrent states, a
     new request starts from a reset row, and a request's entries stay in
     the ring for ``max_len`` calls: a call that would overwrite a live
-    request's oldest entry raises instead."""
+    request's oldest entry raises instead.  A prompt of n tokens counts as
+    n calls (`prefill_request`)."""
 
     def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int = 128,
                  seed: int = 0, dtype=jnp.float32):
@@ -55,17 +59,30 @@ class ModelServer:
         self._born = np.zeros((n_slots,), np.int64)  # call at row's prefill
         self._decode = jax.jit(self._decode_rows)
         self._reset_row = jax.jit(lambda c, row: reset_cache_row(cfg, c, row))
+        # chunked prefill where every block is full-context attention; a
+        # recurrent state, a window or MoE capacity makes a chunk another
+        # computation, so those stacks replay the prompt token by token
+        self.chunk = (min(PREFILL_CHUNK, max_len) if all(
+            b.kind == "attn" and b.window == 0 for b in cfg.blocks) else 0)
+        self._chunk_call = jax.jit(self._prefill_chunk, donate_argnums=4)
 
     def _decode_rows(self, params, tokens, positions, caches):
         logits, new = decode_step(params, self.cfg, tokens, positions, caches)
         return logits, keep_cache_rows(self.cfg, new, caches, positions[:, 0] >= 0)
 
-    def _call(self, rows):
-        """One decode call in which only ``rows`` write live entries."""
-        if (self._calls - self._born[rows] >= self.max_len).any():
+    def _prefill_chunk(self, params, tokens, positions, row, caches):
+        return prefill_chunk(params, self.cfg, tokens, positions, caches, row)
+
+    def _guard(self, rows, calls):
+        """Raise if ``calls`` more calls would overwrite an entry of ``rows``."""
+        if (self._calls + calls - 1 - self._born[rows] >= self.max_len).any():
             raise RuntimeError(
                 f"the {self.max_len}-entry KV ring would overwrite a live "
                 "request's entries; raise max_len")
+
+    def _call(self, rows):
+        """One decode call in which only ``rows`` write live entries."""
+        self._guard(rows, 1)
         pos = np.full_like(self.positions, -1)
         pos[rows] = self.positions[rows]
         # a copy: the CPU backend may alias a numpy argument, and
@@ -77,12 +94,20 @@ class ModelServer:
         return logits
 
     def prefill_request(self, req: Request):
-        """Row prefill: replay the prompt through decode steps (row-isolated
-        caches make per-row prefill exact; a production engine would batch
-        prefills — see DESIGN.md §serving).  Phase spans (under a profiler
-        session): ``serve.prefill`` around ``serve.reset``, ``serve.replay``
-        (the host's side of the per-token calls) and ``serve.prompt_logits``
-        (the wait for them and the copy of the last row's logits)."""
+        """Row prefill.  Where every block is full-context attention the
+        prompt runs in calls of `chunk` tokens (the last one padded), one
+        compiled program for every length: each call attends the chunk's
+        queries over the row's ring entries and its own, causally by
+        position, and leaves the ring as that many decode calls in which
+        only this row is live would (its entries at the next slots, every
+        other row's entries there empty, the cursor and `_calls` advanced
+        by the valid tokens); only the last token's logits are formed.
+        Other stacks replay the prompt through one decode call per token
+        (row-isolated caches make that exact).  Phase spans (under a
+        profiler session): ``serve.prefill`` around ``serve.reset``,
+        ``serve.replay`` (the host's side of the prompt's calls; a
+        ``prefill_chunk`` count per chunk call) and ``serve.prompt_logits``
+        (the wait for them and the copy of the last token's logits)."""
         slot = req.slot
         n = len(req.prompt)
         with span("serve.prefill", n):
@@ -90,14 +115,36 @@ class ModelServer:
                 self.caches = self._reset_row(self.caches, slot)
             self._born[slot] = self._calls
             with span("serve.replay", n):
-                for i, tok in enumerate(req.prompt):
-                    self.tokens[slot, 0] = tok
-                    self.positions[slot, 0] = i
-                    logits = self._call([slot])
+                fill = self._prefill_chunks if self.chunk else self._replay
+                logits = fill(slot, req.prompt)
             self.row_pos[slot] = n
             with span("serve.prompt_logits"):
                 count("d2h")
-                req._last_logits = np.asarray(logits[slot])
+                req._last_logits = np.asarray(logits)
+
+    def _replay(self, slot, prompt):
+        """One decode call per prompt token; the row's last logits."""
+        for i, tok in enumerate(prompt):
+            self.tokens[slot, 0] = tok
+            self.positions[slot, 0] = i
+            logits = self._call([slot])
+        return logits[slot]
+
+    def _prefill_chunks(self, slot, prompt):
+        """The prompt's chunk calls; the last one's logits."""
+        T = self.chunk
+        at = np.arange(T, dtype=np.int32)
+        for c in range(0, len(prompt), T):
+            m = min(T, len(prompt) - c)
+            self._guard([slot], m)
+            tokens = np.zeros(T, np.int32)
+            tokens[:m] = prompt[c:c + m]
+            count("prefill_chunk")
+            logits, self.caches = self._chunk_call(
+                self.params, tokens, np.where(at < m, c + at, -1),
+                np.int32(slot), self.caches)
+            self._calls += m
+        return logits
 
     def step_fn(self, active_reqs):
         """Next-token logits of each request: a request's first token comes

@@ -86,6 +86,39 @@ def kv_cache_append(cache, k, v, positions, ring_write: bool = False):
     return {"k": kc, "v": vc, "pos": pc, "ptr": cache["ptr"] + 1}
 
 
+def kv_cache_write_chunk(cache, row, k, v, positions, ax: int = 0):
+    """Write one row's chunk of entries as that many `kv_cache_append`
+    calls in which only ``row`` is live would: entry i at ring slot
+    (ptr + i) mod capacity, every other row's entry at those slots empty
+    (-1), the cursor advanced by the number of valid entries.  Padding
+    (position -1) writes nothing.  ``ax`` is the batch axis (1 for a
+    unit-stacked cache); k/v (…, 1, T, KV, hd) with the row at ``ax``,
+    positions (T,).  Other rows' K/V at those slots are left as they
+    are: position -1 hides them.
+
+    Each slot of the row's ring takes its entry (or keeps its own), and
+    the row goes back whole: a dynamic-update-slice along the batch axis,
+    in place when the cache is donated, whatever the ring's layout."""
+    B, C = cache["pos"].shape[ax:ax + 2]
+    T = positions.shape[0]
+    n = jnp.sum(positions >= 0, dtype=jnp.int32)
+    ptr = jnp.ravel(cache["ptr"])[0]  # every layer's cursor is the same
+    i = (jnp.arange(C, dtype=jnp.int32) - ptr) % C  # each slot's entry index
+    put = i < n
+    src = jnp.minimum(i, T - 1)
+    z = jnp.zeros((), jnp.int32)
+    out = {"ptr": cache["ptr"] + n}
+    for name, new in (("k", k), ("v", v)):
+        at = (z,) * ax + (row,) + (z,) * (new.ndim - ax - 1)
+        old = jax.lax.dynamic_slice_in_dim(cache[name], row, 1, ax)
+        val = jnp.take(new, src, axis=ax + 1).astype(old.dtype)
+        out[name] = jax.lax.dynamic_update_slice(
+            cache[name], jnp.where(put[:, None, None], val, old), at)
+    mine = jnp.where(jnp.arange(B)[:, None] == row, positions[src], -1)
+    out["pos"] = jnp.where(put, mine, cache["pos"])
+    return out
+
+
 def _ring_write_sharded(cache, k, v, positions, slot, shard_axes):
     """Owning-shard single-slot write under shard_map (manual over the
     cache's sequence-sharding axes, auto elsewhere)."""

@@ -205,6 +205,47 @@ def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 
     return constrain(out, "batch", "seq", "heads")
 
 
+def chunk_attention(q, k, v, q_positions, ring_k, ring_v, ring_positions):
+    """Prompt-chunk attention of one row: its T queries over its KV ring
+    and the chunk's own keys, causal by position (a key is kept iff
+    0 ≤ kpos ≤ qpos; position -1 marks an empty ring entry or padding).
+    The ring row may hold only this sequence's earlier entries, so a chunk
+    that starts the sequence (position 0) attends over its own keys alone
+    and skips the ring.  Same numerics as `single_query_attention`: bf16
+    operands in the cache's dtype, f32 scores, softmax and accumulation.
+
+    q: (1, T, H, hd); k/v: (1, T, KV, hd); q_positions (1, T);
+    ring_k/ring_v: (1, C, KV, hd); ring_positions (1, C)."""
+    B, T, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    scale = 1.0 / math.sqrt(hd)
+    qg = q.reshape(B, T, KV, G, hd).astype(ring_k.dtype)
+    k, v = k.astype(ring_k.dtype), v.astype(ring_v.dtype)
+
+    def attend(kk, vv, kpos):
+        s = jnp.einsum("btkgd,bckd->bkgtc", qg, kk,
+                       preferred_element_type=jnp.float32) * scale
+        kp = kpos[:, None, None, None, :]
+        mask = (kp >= 0) & (q_positions[:, None, None, :, None] >= kp)
+        s = jnp.where(mask, s, -jnp.inf)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        m = jnp.where(jnp.isfinite(m), m, 0.0)
+        p = jnp.where(mask, jnp.exp(s - m), 0.0)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        ctx = jnp.einsum("bkgtc,bckd->bkgtd", p.astype(vv.dtype), vv,
+                         preferred_element_type=jnp.float32)
+        ctx = ctx / jnp.maximum(l, 1e-30)
+        return ctx.transpose(0, 3, 1, 2, 4).reshape(B, T, H, hd).astype(q.dtype)
+
+    return jax.lax.cond(
+        q_positions[0, 0] == 0,
+        lambda: attend(k, v, q_positions),
+        lambda: attend(jnp.concatenate([ring_k, k], axis=1),
+                       jnp.concatenate([ring_v, v], axis=1),
+                       jnp.concatenate([ring_positions, q_positions], axis=1)))
+
+
 # -------------------------------------------------------- attention block ---
 
 

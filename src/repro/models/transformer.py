@@ -9,6 +9,7 @@ Modes (static):
   train    — full-sequence forward, no caches, chunked-CE loss
   prefill  — full-sequence forward, writes KV caches / recurrent states
   decode   — one token per row against the caches
+  chunk    — one row's prompt chunk against that row's KV ring
 
 Frontend stubs (per assignment): "audio" consumes precomputed frame
 embeddings (B,S,D); "vision" prepends precomputed patch embeddings (B,P,D)
@@ -26,6 +27,7 @@ from ..configs.base import Block, ModelConfig
 from ..parallel.sharding import constrain
 from .cache import (
     kv_cache_append,
+    kv_cache_write_chunk,
     kv_cache_write_prefill,
     make_kv_cache,
     make_mlstm_state,
@@ -37,6 +39,7 @@ from .layers import (
     MLP_INIT,
     attn_output,
     blockwise_attention,
+    chunk_attention,
     init_attention,
     init_embedding,
     init_rms_norm,
@@ -224,6 +227,12 @@ def block_forward(spec: Block, cfg: ModelConfig, p, x, positions, cache, mode: s
             ctx = blockwise_attention(q, k, v, positions, positions, window=spec.window,
                                       kv_block=kv_block)
             new_cache = kv_cache_write_prefill(cache, k, v, positions)
+        elif mode == "chunk":  # one row's prompt chunk over its ring entries
+            # (``cache``: that row's view); the new entries are returned for
+            # `prefill_chunk` to write
+            ctx = chunk_attention(q, k, v, positions, cache["k"], cache["v"],
+                                  cache["pos"])
+            new_cache = {"k": k, "v": v}
         else:  # decode — dense single-query path (scan-free; with the cache
             # sequence sharded over `model` the partitioner emits the
             # flash-decode LSE-merge all-reduces)
@@ -475,6 +484,32 @@ def prefill(params, cfg: ModelConfig, batch, caches, unroll_units=False, kv_bloc
     h, caches, _ = forward_hidden(params, cfg, x, positions, caches, "prefill", remat="none",
                                   unroll_units=unroll_units, kv_block=kv_block)
     logits = jnp.einsum("bd,dv->bv", h[:, -1, :], lm_head(params, cfg).astype(h.dtype))
+    return logits.astype(jnp.float32), caches
+
+
+def prefill_chunk(params, cfg: ModelConfig, tokens, positions, caches, row):
+    """One prompt chunk of batch row ``row`` through a stack of
+    full-context attention blocks: tokens and positions (T,), position -1
+    marking padding.  Each block's queries attend over the row's ring
+    entries and the chunk's own (`layers.chunk_attention`); the valid
+    entries then land where as many decode calls would put them
+    (`cache.kv_cache_write_chunk`).  Returns (logits (V,) f32 of the last
+    valid token, caches)."""
+    x = params["embed"][tokens[None]]
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+    rows = _map_block_caches(
+        lambda spec, ax, c: {n: jax.lax.dynamic_slice_in_dim(c[n], row, 1, ax)
+                             for n in ("k", "v", "pos")}, cfg, caches)
+    h, new, _ = forward_hidden(params, cfg, x, positions[None], rows, "chunk",
+                               remat="none")
+    last = jnp.sum(positions >= 0) - 1
+    h_last = jax.lax.dynamic_index_in_dim(h[0], last, keepdims=False)
+    logits = jnp.einsum("d,dv->v", h_last, lm_head(params, cfg).astype(h.dtype))
+    caches = _map_block_caches(
+        lambda spec, ax, c, n: kv_cache_write_chunk(c, row, n["k"], n["v"],
+                                                    positions, ax),
+        cfg, caches, new)
     return logits.astype(jnp.float32), caches
 
 

@@ -48,18 +48,19 @@ def test_serve_driver_fcfs():
 
 
 def test_model_server_replay_matches_prefill():
-    """Each request's token-by-token prompt replay through `ModelServer`,
-    and each of its decode calls, equals the model's own `prefill` of the
-    same tokens, for requests that reuse a slot and share calls with other
-    rows."""
+    """Each request's prompt prefill through `ModelServer` (in chunk calls,
+    as for every full-attention stack), and each of its decode calls,
+    equals the model's own `prefill` of the same tokens, for requests that
+    reuse a slot and share calls with other rows."""
     _replay_matches_prefill("qwen2-0.5b")
 
 
 @pytest.mark.parametrize("arch", ["recurrentgemma-9b", "xlstm-350m"])
 def test_model_server_recurrent_rows_isolated(arch):
-    """The same for models with recurrent blocks: a reused slot starts
-    from a zero state, and a row's state does not move in the calls that
-    replay another row's prompt."""
+    """The same for models with recurrent blocks, whose prompts are
+    replayed token by token: a reused slot starts from a zero state, and a
+    row's state does not move in the calls that replay another row's
+    prompt."""
     _replay_matches_prefill(arch)
 
 
@@ -73,6 +74,7 @@ def _replay_matches_prefill(arch, tok=7):
 
     cfg = get_smoke_config(arch)
     server = ModelServer(cfg, 2, max_len=64)
+    assert bool(server.chunk) == (arch == "qwen2-0.5b")  # else: replay
     got = {}  # rid -> the logits each engine step gave it
 
     def step_fn(active):

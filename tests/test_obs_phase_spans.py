@@ -98,20 +98,24 @@ def test_a_traced_step_records_the_span_tree(server, log, tmp_path):
               "serve.prompt_logits": "serve.prefill",
               "serve.feed": "serve.decode", "serve.wait": "serve.decode",
               "serve.fetch": "serve.decode"}
-    spans = [r for r in recs if r[0] != "d2h"]
+    spans = [r for r in recs if r[0] not in ("d2h", "prefill_chunk")]
     assert {r[0] for r in spans} == set(parent) | {"sched.step"}
     for r in spans:
         assert r[2] >= r[1]
         if r[0] != "sched.step":
             assert r[3] == parent[r[0]], r
     first, second = steps
-    names1 = [r[0] for r in _under(recs, first) if r[0] != "d2h"]
+    names1 = [r[0] for r in _under(recs, first)
+              if r[0] not in ("d2h", "prefill_chunk")]
     assert "serve.prefill" not in names1
     assert names1.count("serve.decode") == 1
     prefill = [r for r in _under(recs, second) if r[0] == "serve.prefill"]
     assert [r[4] for r in prefill] == [4]  # n: the prompt's tokens
     assert [r[4] for r in _under(recs, prefill[0])
             if r[0] == "serve.replay"] == [4]
+    # one chunk call for the 4-token prompt, counted inside the replay
+    assert [(r[3], r[4]) for r in recs if r[0] == "prefill_chunk"] == [
+        ("serve.replay", 1)]
     for step in steps:
         assert [r[4] for r in _under(recs, step)
                 if r[0] in ("serve.decode", "serve.fetch")] == [2, 2]
@@ -131,6 +135,26 @@ def test_a_traced_step_records_the_span_tree(server, log, tmp_path):
     names = {ev.name for plane in ProfileData.from_file(str(pb)).planes
              for line in plane.lines for ev in line.events}
     assert {"repro." + n for n in parent} | {"repro.sched.step"} <= names
+
+
+@pytest.mark.parametrize("n", [1, 255, 256, 257, 515])
+def test_prefill_counts_one_chunk_call_per_chunk(n, log, tmp_path):
+    """``prefill_chunk`` counts ceil(n / chunk) calls for an n-token
+    prompt, and the prompt's logits are still one readback."""
+    srv = ModelServer(get_smoke_config("qwen2-0.5b"), 1, max_len=600)
+    assert srv.chunk == 256
+    req = Request(rid=0, prompt=[1 + i % 7 for i in range(n)],
+                  max_new_tokens=1)
+    req.slot = 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.prefill_request(req)
+    finally:
+        jax.profiler.stop_trace()
+    recs = log.records(0, 2**63)
+    assert sum(r[4] for r in recs if r[0] == "prefill_chunk") == -(-n // 256)
+    assert [(r[3], r[4]) for r in recs if r[0] == "d2h"] == [
+        ("serve.prompt_logits", 1)]
 
 
 def test_a_traced_megastep_records_its_launch_and_drain(log, tmp_path):

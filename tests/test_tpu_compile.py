@@ -123,6 +123,42 @@ def test_qwen2_decode_step_fits_v5e(chip):
     assert total < HBM_BYTES, total
 
 
+def test_qwen2_prefill_chunk_fits_beside_decode_on_v5e(chip):
+    """`ModelServer`'s prompt-chunk program at the benchmark's qwen2-0.5b
+    widths (16 slots, an 8,192-entry ring, 256-token chunks) updates the
+    donated ring in place and needs no more device memory than the decode
+    program over the same ring."""
+    from repro.configs.registry import get_config
+    from repro.models.transformer import (decode_step, init_caches,
+                                          init_params, prefill_chunk)
+
+    cfg = get_config("qwen2-0.5b")
+    place = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), t)
+    params = place(jax.eval_shape(
+        lambda k: init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(
+        lambda: init_caches(cfg, 16, 8192, jnp.bfloat16)))
+    ring = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    tok, pos = _shapes(chip, ((16, 1), jnp.int32), ((16, 1), jnp.int32))
+    chunk, row = _shapes(chip, ((256,), jnp.int32), ((), jnp.int32))
+
+    def footprint(compiled):
+        m = compiled.memory_analysis()
+        return m, (m.argument_size_in_bytes + m.output_size_in_bytes
+                   + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+    _, decode = footprint(jax.jit(
+        lambda p, t, q, c: decode_step(p, cfg, t, q, c)
+    ).lower(params, tok, pos, caches).compile())
+    m, prefill = footprint(jax.jit(
+        lambda p, t, q, r, c: prefill_chunk(p, cfg, t, q, c, r),
+        donate_argnums=4).lower(params, chunk, chunk, row, caches).compile())
+    assert m.alias_size_in_bytes >= ring > 1.6e9  # no second ring
+    assert m.temp_size_in_bytes < ring / 2
+    assert prefill <= decode, (prefill, decode)
+
+
 def test_served_megastep_compiles_for_v5e(chip, monkeypatch):
     """The served megastep — four weighted tenants, the fused admission
     kernel, a 4096-block pool, chunked prefill and the prefix cache — at
