@@ -25,8 +25,7 @@ import numpy as np
 
 from . import record, schedule_ref, traffic
 from .clock import CompileClock
-from .model import ModelSpec, check_tree, make_params, program_config, \
-    tree_paths
+from .model import check_tree, make_params, tree_paths
 from .peaks import peaks
 from .record import Call, ReqRecord, Run, Span
 from .spec import Bench
@@ -83,7 +82,7 @@ class Harness:
         n = len(req.prompt)
         self.spans.append(Span("prefill", t0, t1, n))
         dt = (t1 - t0) / max(n, 1)
-        self.calls.extend(Call(t0 + i * dt, t0 + (i + 1) * dt, [i])
+        self.calls.extend(Call(t0 + i * dt, t0 + (i + 1) * dt, [i], "prefill")
                           for i in range(n))
         self.n_calls += n
         self.ring_high = max(self.ring_high, n)
@@ -99,7 +98,7 @@ class Harness:
             self.n_calls += 1
             self.spans.append(Span("decode", t0, t1, len(decoding)))
             self.calls.append(Call(t0, t1, [len(r.prompt) + len(r.out_tokens)
-                                            - 1 for r in decoding]))
+                                            - 1 for r in decoding], "decode"))
         return logits
 
     def sample_fn(self, logits):
@@ -208,7 +207,8 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
     cfg = bench.config(cell["config"])
     mix = bench.traffic(cell["traffic"])
     cfile = bench.cell_file(name)
-    spec = ModelSpec.from_config(cfg)
+    family = bench.family(cell["config"])
+    spec = family.spec(cfg)
     slots, ring = int(cfg["slots"]), int(cfile["kv_ring"])
     tenants = traffic.weights(mix)
     tindex = {t: i for i, t in enumerate(tenants)}
@@ -222,13 +222,13 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
         mem[phase] = [st.get("bytes_in_use"), st.get("peak_bytes_in_use"),
                       round(time.perf_counter() - t_start + age_at_start, 3)]
 
-    server = ModelServer(program_config(cfg), slots, max_len=ring, seed=0,
-                         dtype=dtype)
+    server = ModelServer(family.program_config(cfg), slots, max_len=ring,
+                         seed=0, dtype=dtype)
     note("server")
     served = tree_paths(server.params)
     server.params = None  # the program's own draw; freed before ours
     gc.collect()
-    params = make_params(spec, seed, dtype)
+    params = make_params(family.leaves(spec), seed, dtype)
     check_tree(tree_paths(params), served)
     server.params = params
     note("weights")
@@ -393,23 +393,25 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
         served = list(reqs[rec.rid].out_tokens)
         sample.append((reqs[rec.rid].prompt, served))
         ntok += len(served)
+    if not sample:
+        raise Fault("no request finished in the window: the reference has "
+                    "nothing to compare")
 
     run = Run(cell=name, config=cfg, spec=spec, peaks=chip_peaks,
               t_open=t_open, t_close=t_close, setup_s=setup_s,
               requests=list(recs.values()), spans=h.spans[s0:],
               calls=h.calls[k0:], steps=rounds[0] - r0,
               out_tokens=out_tokens[0],
-              trace_host=((trace_host or [t_open])[0], t_close))
+              trace_host=((trace_host or [t_open])[0], t_close),
+              family=family)
     attempted = sum(1 for r in recs.values()
                     if (r.due is not None and t_open <= r.due < t_close)
                     or r.n_window)
     del eng, server, params, h.server, warm
     gc.collect()
 
-    from .reference import served_gaps
-
     t_ref = time.perf_counter()
-    gaps, ctl = served_gaps(spec, seed, sample, control=control)
+    gaps, ctl = family.served_gaps(spec, seed, sample, control=control)
     t_ref = time.perf_counter() - t_ref
     limits = cfile.get("limits", {})
     detail = {"sample_requests": len(sample), "sample_tokens": int(len(gaps)),

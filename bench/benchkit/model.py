@@ -1,16 +1,25 @@
-"""The served model as the benchmark sees it: its sizes from the cell's
-configuration file, the operations and bytes a decode call needs, and
-the seeded weights.
+"""The seeded weights of a served model, whatever its architecture.
 
-Everything here is the yardstick's own.  The program is told its sizes
-through `repro.configs.base.ModelConfig` (built in `program_config`), and
-its weights are made here, so the plain reference can make the same
-values again without taking anything from the program.
+A family module (`bench/families/<architecture>.py`) lists its leaves as
+path → `Leaf`; everything here draws them.  Every leaf is drawn from its
+own key: fold_in(fold_in(seed key, crc32 of its path), layer).  Matrices
+are N(0, 1/fan_in); RMSNorm scales 1 + 0.1 N; biases 0.1 N.  A family
+lays its leaves out as the program's `models.transformer.init_params`
+does (checked by `check_tree`): a leaf stacked over the repeated unit's
+layers holds them on its leading axis, and a path part that is a number
+(``prefix/0/...``) is an index into a list.  The reference draws each
+layer again with `layer_leaf`; XLA may round a rare element of the f32
+draw differently inside the larger program, so the two agree to the last
+bit of bf16 on all but about one in 10^5.
+
+Everything here is the yardstick's own: the program is told its sizes by
+the family's `program_config`, and its weights are made here, so the
+plain reference can make the same values again without taking anything
+from the program.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import zlib
 from dataclasses import dataclass
 
@@ -20,152 +29,11 @@ BF16_BYTES = 2
 
 
 @dataclass(frozen=True)
-class ModelSpec:
-    """A Qwen2-style decoder (RMSNorm, RoPE, GQA attention with q/k/v
-    bias, SwiGLU MLP), sized by its published `config.json` keys."""
-
-    name: str
-    hidden: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    ffn: int
-    vocab: int
-    layers: int
-    rope_theta: float
-    rms_eps: float
-    tied: bool
-
-    @classmethod
-    def from_config(cls, cfg: dict) -> "ModelSpec":
-        h, n = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
-        return cls(
-            name=cfg["name"], hidden=h, heads=n,
-            kv_heads=int(cfg["num_key_value_heads"]),
-            head_dim=int(cfg.get("head_dim", h // n)),
-            ffn=int(cfg["intermediate_size"]), vocab=int(cfg["vocab_size"]),
-            layers=int(cfg["num_hidden_layers"]),
-            rope_theta=float(cfg["rope_theta"]),
-            rms_eps=float(cfg["rms_norm_eps"]),
-            tied=bool(cfg["tie_word_embeddings"]))
-
-    # ------------------------------------------------------------ sizes --
-
-    def layer_matmul_params(self) -> int:
-        d, hd = self.hidden, self.head_dim
-        attn = d * (self.heads + 2 * self.kv_heads) * hd + self.heads * hd * d
-        return attn + 3 * d * self.ffn
-
-    def layer_params(self) -> int:
-        bias = (self.heads + 2 * self.kv_heads) * self.head_dim
-        return self.layer_matmul_params() + bias + 2 * self.hidden
-
-    def params(self) -> int:
-        emb = self.vocab * self.hidden
-        return (self.layers * self.layer_params() + self.hidden
-                + emb * (1 if self.tied else 2))
-
-    def kv_bytes_per_token(self) -> int:
-        """K and V of one token in every layer, bf16."""
-        return self.layers * 2 * self.kv_heads * self.head_dim * BF16_BYTES
-
-
-def decode_call_cost(spec: ModelSpec, contexts) -> tuple[float, float]:
-    """FLOPs and HBM bytes that one decode call needs, whatever implements
-    it.  ``contexts`` holds, for each row that writes a live entry, the
-    number of earlier tokens of its sequence (its position).
-
-    Bytes: every weight read once (the LM head once; an untied embedding
-    only as the rows gathered), each live row's earlier K/V entries read
-    once, its new entry written once, and its f32 logits written.  Rows
-    that take no part in the call need nothing.  FLOPs: 2 per multiply-add
-    of every matmul (layers and head) for each live row, plus QK^T and PV
-    over its context including the new token."""
-    ctx = np.asarray(contexts, np.int64)
-    rows = len(ctx)
-    if rows == 0:
-        return 0.0, 0.0
-    d = spec.hidden
-    head = spec.vocab * d
-    weights = (spec.layers * spec.layer_params() + d + head) * BF16_BYTES
-    if not spec.tied:
-        weights += rows * d * BF16_BYTES  # embedding rows gathered
-    kv = spec.kv_bytes_per_token()
-    nbytes = weights + kv * (int(ctx.sum()) + rows) + rows * spec.vocab * 4
-    per_row = 2 * (spec.layers * spec.layer_matmul_params() + head)
-    attn = 4 * spec.layers * spec.heads * spec.head_dim * (ctx + 1)
-    flops = rows * per_row + int(attn.sum())
-    return float(flops), float(nbytes)
-
-
-def program_config(cfg: dict):
-    """The program's `ModelConfig` for this configuration file: the
-    registry entry named by ``program_arch`` with every published size
-    taken from the file.  Raises where the registry entry is not the
-    architecture the file describes."""
-    from repro.configs.registry import get_config
-
-    base = get_config(cfg["program_arch"])
-    spec = ModelSpec.from_config(cfg)
-    plain = (len(base.unit) == 1 and base.unit[0].kind == "attn"
-             and base.unit[0].window == 0 and not base.prefix
-             and not base.suffix and base.mlp_kind == "swiglu"
-             and base.qkv_bias and not base.norm_plus_one
-             and not base.embed_scale and not base.sandwich_norms)
-    if not plain:
-        raise ValueError(f"{cfg['program_arch']} is not a Qwen2-style stack")
-    return dataclasses.replace(
-        base, d_model=spec.hidden, n_heads=spec.heads,
-        n_kv_heads=spec.kv_heads, head_dim=spec.head_dim, d_ff=spec.ffn,
-        vocab=spec.vocab, num_units=spec.layers, rope_theta=spec.rope_theta,
-        tie_embeddings=spec.tied)
-
-
-# ------------------------------------------------------------- weights ----
-#
-# Every leaf is drawn from its own key: fold_in(fold_in(seed key, crc32 of
-# its path), layer).  Matrices are N(0, 1/fan_in); RMSNorm scales 1 + 0.1 N;
-# q/k/v biases 0.1 N.  The leaves are laid out as the program's
-# `models.transformer.init_params` lays them (checked by `check_tree`).
-# The reference draws each layer again with `layer_leaf`; XLA may round a
-# rare element of the f32 draw differently inside the larger program, so
-# the two agree to the last bit of bf16 on all but about one in 10^5.
-
-
-@dataclass(frozen=True)
 class Leaf:
     shape: tuple  # of one layer for stacked leaves
     kind: str  # "matrix" | "norm" | "bias"
     fan_in: int = 1
-    stacked: bool = False
-
-
-def leaves(spec: ModelSpec) -> dict[str, Leaf]:
-    d, H, KV, hd, F, V = (spec.hidden, spec.heads, spec.kv_heads,
-                          spec.head_dim, spec.ffn, spec.vocab)
-    out = {
-        "embed": Leaf((V, d), "matrix", d),
-        "final_ln": Leaf((d,), "norm"),
-    }
-    if not spec.tied:
-        out["head"] = Leaf((d, V), "matrix", d)
-    layer = {
-        "units/b0/ln1": Leaf((d,), "norm"),
-        "units/b0/ln2": Leaf((d,), "norm"),
-        "units/b0/attn/wq": Leaf((d, H, hd), "matrix", d),
-        "units/b0/attn/wk": Leaf((d, KV, hd), "matrix", d),
-        "units/b0/attn/wv": Leaf((d, KV, hd), "matrix", d),
-        "units/b0/attn/wo": Leaf((H, hd, d), "matrix", H * hd),
-        "units/b0/attn/bq": Leaf((H, hd), "bias"),
-        "units/b0/attn/bk": Leaf((KV, hd), "bias"),
-        "units/b0/attn/bv": Leaf((KV, hd), "bias"),
-        "units/b0/mlp/wi": Leaf((d, F), "matrix", d),
-        "units/b0/mlp/wg": Leaf((d, F), "matrix", d),
-        "units/b0/mlp/wo": Leaf((F, d), "matrix", F),
-    }
-    out.update({k: dataclasses.replace(v, stacked=True)
-                for k, v in layer.items()})
-    return out
+    stacked: int = 0  # layers stacked on the leading axis; 0: one array
 
 
 def seed_key(seed: int):
@@ -203,18 +71,27 @@ def layer_leaf(key, path: str, leaf: Leaf, layer: int, dtype):
     return _draw(leaf_key(key, path, layer), leaf, dtype)
 
 
-def make_params(spec: ModelSpec, seed: int, dtype):
-    """Every weight in ``dtype``, on the device, in one jitted call."""
-    import jax
+def _lists(node):
+    """Nested dicts whose keys are 0..n-1 (as strings) become lists."""
+    if not isinstance(node, dict):
+        return node
+    out = {k: _lists(v) for k, v in node.items()}
+    if out and sorted(out) == sorted(map(str, range(len(out)))):
+        return [out[str(i)] for i in range(len(out))]
+    return out
 
-    specs = leaves(spec)
+
+def make_params(specs: dict[str, Leaf], seed: int, dtype):
+    """Every leaf of ``specs`` (a family's `leaves`) in ``dtype``, on the
+    device, in one jitted call."""
+    import jax
 
     def build(key):
         tree: dict = {}
         for path, leaf in specs.items():
             if leaf.stacked:
                 keys = jax.vmap(lambda l, p=path: leaf_key(key, p, l))(
-                    jax.numpy.arange(spec.layers))
+                    jax.numpy.arange(leaf.stacked))
                 val = jax.vmap(lambda k, lf=leaf: _draw(k, lf, dtype))(keys)
             else:
                 val = _draw(leaf_key(key, path), leaf, dtype)
@@ -223,7 +100,7 @@ def make_params(spec: ModelSpec, seed: int, dtype):
             for p in parents:
                 node = node.setdefault(p, {})
             node[last] = val
-        return tree
+        return _lists(tree)
 
     return jax.jit(build)(seed_key(seed))
 

@@ -10,10 +10,11 @@ around the calls it makes into the program:
              ``n`` = rows decoded
     sample   the engine's ``sample_fn`` (argmax on the host)
 
-Every decode-program call (one per replayed prompt token, one per decode
-span) is kept with the positions of the rows that write a live entry, so
-that its needed operations and bytes can be counted (`model.
-decode_call_cost`).
+Every decode call (``kind`` "decode", one per decode span) is kept with
+the positions of the rows that write a live entry, and so is every prompt
+token (``kind`` "prefill", one record per token, at its position), so that
+the operations and bytes they need can be counted (the family's
+``call_cost``).
 """
 
 from __future__ import annotations
@@ -36,6 +37,7 @@ class Call:
     t0: float
     t1: float
     contexts: list[int]
+    kind: str  # "decode" | "prefill"
 
 
 @dataclass
@@ -56,7 +58,7 @@ class ReqRecord:
 class Run:
     cell: str
     config: dict
-    spec: object  # model.ModelSpec
+    spec: object  # the family's spec of the configuration
     peaks: dict
     t_open: float
     t_close: float
@@ -68,6 +70,7 @@ class Run:
     out_tokens: int  # tokens produced in the window
     trace: object = None  # trace.DeviceTrace of the traced run
     trace_host: tuple = (0.0, 0.0)  # the traced window on the host clock
+    family: object = None  # the configuration's family module (`spec.Bench`)
 
     @property
     def window_s(self) -> float:

@@ -1,21 +1,41 @@
 """Finds everything of a run by name: the cell in `BENCHMARK.json`, its
-configuration, traffic mix and cell file, and the reader of each metric.
+configuration, the family of its architecture, traffic mix and cell file,
+and the reader of each metric.
 
-    BENCHMARK.json                 cells, metrics and what each moves
-    bench/configs/<config>.json    sizes, dtype, slots (file named there)
-    bench/traffic/<mix>.json       the generator's parameters
-    bench/cells/<cell>.json        the cell's KV ring and its limits
-    bench/metrics/<metric>.py      ``read(run) -> float | None``
+    BENCHMARK.json                       cells, metrics and what each moves
+    bench/configs/<config>.json          sizes, dtype, slots (file named
+                                         there)
+    bench/families/<architecture>.py     sizes, weights' leaves, the
+                                         program's config, costs and the
+                                         plain reference of one
+                                         architecture, named by the
+                                         configuration's ``architectures[0]``
+    bench/traffic/<mix>.json             the generator's parameters
+    bench/cells/<cell>.json              the cell's KV ring and its limits
+    bench/metrics/<metric>.py            ``read(run) -> float | None``
 
-A later cell, configuration, mix or metric is a new file and a new
-entry; nothing here changes for it.
+A later cell, configuration, architecture, mix or metric is a new file
+and a new entry; nothing here changes for it.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
+
+
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # a dataclass looks its module up there
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _ident(name: str) -> str:
+    return name.replace(".", "_").replace("-", "_")
 
 
 class Bench:
@@ -53,10 +73,14 @@ class Bench:
                 if (cell in m["workloads"] if "workloads" in m
                     else m["moves"] in moved)]
 
+    def family(self, config: str):
+        """The family module of a configuration's architecture; the
+        docstring of each module under `bench/families/` lists what one
+        provides."""
+        arch = self.config(config)["architectures"][0]
+        return _load(self.dir / "families" / f"{arch}.py",
+                     f"bench_family_{_ident(arch)}")
+
     def reader(self, metric: str):
-        path = self.dir / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(self.dir / "metrics" / f"{metric}.py",
+                     f"bench_metric_{_ident(metric)}").read

@@ -3,15 +3,19 @@
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q bench/tests
 
 They check that cells, configurations, traffic and metrics are found by
-name; the tails and the open-loop timing; the operation and byte counts
-against a hand count; the trace reduction on a small recorded trace; the
-schedule reference against the engine; that a run refuses to run off the
-TPU; and that a whole run at a tiny size comes out correct, and comes
-out not correct with the timed path broken underneath it.
+name, a second architecture's family among them; the tails and the
+open-loop timing; the operation and byte counts against a hand count, and
+which calls the decode roofline counts; that the weights are the same bit
+for bit as before the families moved out of the harness; the trace
+reduction on a small recorded trace; the schedule reference against the
+engine; that a run refuses to run off the TPU; and that a whole run at a
+tiny size comes out correct, and comes out not correct with the timed
+path broken underneath it.
 """
 
 from __future__ import annotations
 
+import hashlib
 import io
 import json
 import os
@@ -29,8 +33,7 @@ BENCH = ROOT / "bench"
 sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 
 from benchkit import record, schedule_ref, trace, traffic  # noqa: E402
-from benchkit.driver import drive, run_cell  # noqa: E402
-from benchkit.model import ModelSpec, decode_call_cost  # noqa: E402
+from benchkit.driver import Fault, drive, run_cell  # noqa: E402
 from benchkit.spec import Bench  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -41,6 +44,11 @@ def _doc():
     return json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
+def _qwen2():
+    """The Qwen2 family module (every configuration of the benchmark)."""
+    return Bench(ROOT).family("qwen2-0.5b")
+
+
 # ------------------------------------------------------------- by name ----
 
 
@@ -49,6 +57,9 @@ def test_every_entry_is_found_by_name():
     for cell in b.doc["workloads"]:
         cfg = b.config(cell["config"])
         assert cfg["name"] == cell["config"]
+        fam = b.family(cell["config"])
+        assert fam.spec(cfg).vocab == cfg["vocab_size"]
+        assert fam.program_config(cfg).vocab == cfg["vocab_size"]
         mix = b.traffic(cell["traffic"])
         assert mix["arrivals"]["kind"] in ("poisson", "backlog")
         assert b.cell_file(cell["name"])["kv_ring"] > 0
@@ -119,11 +130,56 @@ def test_a_cell_added_by_files_and_entries_alone(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(doc))
     b = Bench(root)
     assert b.config("extra-model")["name"] == "extra-model"
+    assert b.family("extra-model").__file__.endswith(
+        "families/Qwen2ForCausalLM.py")
     assert b.traffic("extra-mix")["arrivals"]["rate_rps"] == 0.5
     assert b.cell_file("extra-model.extra-mix")["kv_ring"] == 1024
     assert "extra_metric" in {m["name"]
                               for m in b.per_layer("extra-model.extra-mix")}
     assert b.reader("extra_metric")(None) == 42.0
+
+
+SECOND_FAMILY = "PrefixDecoderForCausalLM"
+
+
+def _benchkit_files(root: Path) -> dict[str, bytes]:
+    return {str(f.relative_to(root)): f.read_bytes()
+            for f in sorted((root / "bench/benchkit").rglob("*.py"))}
+
+
+def test_a_family_added_by_files_and_entries_alone(tmp_path):
+    """A configuration of another architecture: its family module, its
+    configuration naming it, a traffic mix and a cell file, and their
+    entries; a tiny run through that family's program config, weights
+    and reference comes out correct, and nothing of the harness
+    changes."""
+    root = _copy_bench(tmp_path)
+    kit = _benchkit_files(root)
+    shutil.copy(BENCH / "tests" / "data" / f"{SECOND_FAMILY}.py",
+                root / "bench" / "families" / f"{SECOND_FAMILY}.py")
+    cfg = dict(_cfg("qwen2-0.5b"), **TINY)
+    cfg.update(name="second", architectures=[SECOND_FAMILY],
+               num_hidden_layers=3)
+    (root / "bench/configs/second.json").write_text(json.dumps(cfg))
+    (root / "bench/traffic/tiny.json").write_text(json.dumps(_tiny_mix()))
+    (root / "bench/cells/second.tiny.json").write_text(json.dumps(
+        {"kv_ring": 2048, "limits": {"logit_gap_mean": TINY_LIMIT}}))
+    doc = json.loads((root / "BENCHMARK.json").read_text())
+    doc["configs"].append({"name": "second", "source": "test",
+                           "file": "bench/configs/second.json",
+                           "reduced": [], "why": "test"})
+    doc["workloads"].append({"name": "second.tiny", "config": "second",
+                             "traffic": "tiny", "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    b = Bench(root)
+    fam = b.family("second")
+    assert fam.__file__.endswith(f"families/{SECOND_FAMILY}.py")
+    program = fam.program_config(cfg)
+    assert len(program.prefix) == 1 and not program.qkv_bias
+    res, _ = _tiny_run(root, "second.tiny")
+    assert res["correct"] is True, res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert _benchkit_files(root) == kit
 
 
 # ------------------------------------------------------ tails and time ----
@@ -215,16 +271,23 @@ def test_every_seed_gets_the_same_work():
 # ------------------------------------------------------------ counting ----
 
 
+def _cfg(name):
+    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
 def _spec(name):
-    return ModelSpec.from_config(
-        json.loads((BENCH / "configs" / f"{name}.json").read_text()))
+    return _qwen2().spec(_cfg(name))
+
+
+def _cost(spec, contexts):
+    return _qwen2().call_cost(spec, record.Call(0.0, 1.0, contexts, "decode"))
 
 
 def test_decode_cost_hand_count_qwen2():
     s = _spec("qwen2-0.5b")
     assert s.params() == 494_032_768
     # one row at position 99 and one at position 0
-    flops, nbytes = decode_call_cost(s, [99, 0])
+    flops, nbytes = _cost(s, [99, 0])
     layer = 896 * 896 + 2 * 896 * 128 + 896 * 896 + 3 * 896 * 4864
     head = 151_936 * 896
     per_row = 2 * (24 * layer + head)
@@ -242,13 +305,57 @@ def test_decode_cost_hand_count_codeqwen_8_layers():
                     + 3 * 4096 * 13_440 + (32 + 8) * 128 + 2 * 4096)
     assert s.params() == 8 * layer_params + 4096 + 2 * 92_416 * 4096
     assert s.params() == 2_380_378_112
-    flops, nbytes = decode_call_cost(s, [10] * 8)
+    flops, nbytes = _cost(s, [10] * 8)
     weights = (8 * layer_params + 4096 + 92_416 * 4096) * 2 + 8 * 4096 * 2
     kv = 8 * 2 * 4 * 128 * 2
     assert nbytes == weights + kv * (8 * 10 + 8) + 8 * 92_416 * 4
     mm = 8 * (4096 * 40 * 128 + 4096 * 4096 + 3 * 4096 * 13_440)
     assert flops == 8 * 2 * (mm + 92_416 * 4096) + 8 * 4 * 8 * 32 * 128 * 11
-    assert decode_call_cost(s, []) == (0.0, 0.0)
+    assert _cost(s, []) == (0.0, 0.0)
+
+
+def test_a_key_the_program_cannot_state_is_refused():
+    """codeqwen1.5-7b publishes RMSNorm eps 1e-5; the program's norms take
+    1e-6 and `ModelConfig` has no field for it."""
+    fam = _qwen2()
+    with pytest.raises(ValueError, match="rms_norm_eps"):
+        fam.program_config(_cfg("codeqwen1.5-7b"))
+    for key, value in [("hidden_act", "gelu"), ("use_sliding_window", True),
+                       ("rope_scaling", {"type": "yarn", "factor": 4.0})]:
+        with pytest.raises(ValueError, match=key):
+            fam.program_config(dict(_cfg("qwen2-0.5b"), **{key: value}))
+    for name in ("qwen2-0.5b", "qwen2-7b"):
+        assert fam.program_config(_cfg(name)).num_units == _cfg(name)[
+            "num_hidden_layers"]
+
+
+def test_decode_roofline_counts_decode_calls_only():
+    """1 MB a row at 1 GB/s is 1 ms of least time per row; two decode
+    calls of 2 and 4 rows against two 10-ms `_decode_rows` programs read
+    (2 + 4) / 20 = 30%, whatever prompt tokens ran beside them."""
+    class Family:
+        @staticmethod
+        def call_cost(spec, call):
+            return 0.0, 1e6 * len(call.contexts)
+
+    decode = [record.Call(1.0, 1.1, [5, 6], "decode"),
+              record.Call(2.0, 2.1, [5, 6, 7, 8], "decode")]
+    prompt = [record.Call(1.2 + i / 100, 1.21 + i / 100, [i], "prefill")
+              for i in range(50)]
+    ms = 10**6
+    programs = [("jit__decode_rows(1)", 100 * ms, 10 * ms),
+                ("jit__prefill_chunk(2)", 300 * ms, 10 * ms),
+                ("jit__decode_rows(3)", 500 * ms, 10 * ms)]
+    t = trace.DeviceTrace(lo=0, hi=1000 * ms, programs=programs)
+    reader = Bench(ROOT).reader("decode_roofline")
+    for calls in (decode, decode + prompt, prompt[:10] + decode):
+        run = _run([])
+        run.calls, run.trace, run.trace_host = calls, t, (0.0, 10.0)
+        run.peaks = {"hbm_bytes_s": 1e9, "bf16_flops": 1e12}
+        run.family = Family
+        assert reader(run) == pytest.approx(30.0)
+    run.calls = prompt
+    assert reader(run) is None
 
 
 # --------------------------------------------------------------- trace ----
@@ -375,22 +482,62 @@ def test_weights_are_drawn_again_by_the_reference():
     import jax
     import jax.numpy as jnp
 
-    from benchkit.model import layer_leaf, leaves, make_params, seed_key
+    from benchkit.model import layer_leaf, make_params, seed_key
 
     spec = _tiny_spec()
-    params = make_params(spec, 2**45 + 11, jnp.bfloat16)
+    leaves = _qwen2().leaves(spec)
+    params = make_params(leaves, 2**45 + 11, jnp.bfloat16)
     key = seed_key(2**45 + 11)
     draw = jax.jit(layer_leaf, static_argnums=(1, 2, 4))
-    for path, leaf in leaves(spec).items():
+    for path, leaf in leaves.items():
         node = params
         for p in path.split("/"):
             node = node[p]
-        for layer in range(spec.layers if leaf.stacked else 1):
+        for layer in range(leaf.stacked or 1):
             again = draw(key, path, leaf, layer, jnp.bfloat16)
             _same_draw(node[layer] if leaf.stacked else node, again)
-    other = make_params(spec, 2**45 + 11 + 2**32, jnp.bfloat16)
+    other = make_params(leaves, 2**45 + 11 + 2**32, jnp.bfloat16)
     assert not np.array_equal(np.asarray(other["embed"]),
                               np.asarray(params["embed"]))
+
+
+def _digest(tree) -> str:
+    import jax
+
+    h = hashlib.sha256()
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    for kp, x in sorted(flat, key=lambda t: jax.tree_util.keystr(t[0])):
+        h.update(jax.tree_util.keystr(kp).encode())
+        h.update(np.asarray(x).view(np.uint16).tobytes())
+    return h.hexdigest()
+
+
+def test_the_qwen2_family_draws_the_weights_it_drew_before():
+    """The digest of every bf16 bit of the tiny model's weights, as the
+    harness drew them before the Qwen2 family moved out of it."""
+    import jax.numpy as jnp
+
+    from benchkit.model import make_params
+
+    params = make_params(_qwen2().leaves(_tiny_spec()), 2**45 + 11,
+                         jnp.bfloat16)
+    assert _digest(params) == (
+        "4980557157612656974809e35eec4d2acb02b2647151ac7db52932fc48efaf30")
+
+
+def test_numbered_path_parts_make_lists():
+    import jax.numpy as jnp
+
+    from benchkit.model import Leaf, make_params, tree_paths
+
+    params = make_params({"prefix/0/w": Leaf((2, 3), "matrix", 2),
+                          "prefix/1/w": Leaf((3,), "norm"),
+                          "units/b0/w": Leaf((4,), "bias", stacked=5)},
+                         7, jnp.float32)
+    assert isinstance(params["prefix"], list) and len(params["prefix"]) == 2
+    assert tree_paths(params) == {"prefix/0/w": ((2, 3), "float32"),
+                                  "prefix/1/w": ((3,), "float32"),
+                                  "units/b0/w": ((5, 4), "float32")}
 
 
 TINY = dict(name="tiny", hidden_size=256, intermediate_size=512,
@@ -409,7 +556,16 @@ TINY_SECONDS = 4.0
 def _tiny_spec():
     cfg = json.loads((BENCH / "configs" / "qwen2-0.5b.json").read_text())
     cfg.update(TINY)
-    return ModelSpec.from_config(cfg)
+    return _qwen2().spec(cfg)
+
+
+def _tiny_mix():
+    """A 16-deep backlog of short requests."""
+    mix = json.loads((BENCH / "traffic" / "codegen.json").read_text())
+    mix["arrivals"].update(depth=16, pool=64)
+    mix["prompt"] = {"median": 8, "sigma": 0.5, "min": 2, "max": 24}
+    mix["output"] = {"median": 64, "sigma": 0.5, "min": 32, "max": 128}
+    return mix
 
 
 @pytest.fixture(scope="module")
@@ -420,11 +576,7 @@ def tiny_root(tmp_path_factory):
     cfg = json.loads((BENCH / "configs" / "qwen2-0.5b.json").read_text())
     cfg.update(TINY)
     (root / "bench/configs/tiny.json").write_text(json.dumps(cfg))
-    mix = json.loads((BENCH / "traffic" / "codegen.json").read_text())
-    mix["arrivals"].update(depth=16, pool=64)
-    mix["prompt"] = {"median": 8, "sigma": 0.5, "min": 2, "max": 24}
-    mix["output"] = {"median": 64, "sigma": 0.5, "min": 32, "max": 128}
-    (root / "bench/traffic/tiny.json").write_text(json.dumps(mix))
+    (root / "bench/traffic/tiny.json").write_text(json.dumps(_tiny_mix()))
     (root / "bench/cells/tiny.tiny.json").write_text(json.dumps(
         {"kv_ring": 2048, "limits": {"logit_gap_mean": TINY_LIMIT}}))
     doc = json.loads((root / "BENCHMARK.json").read_text())
@@ -440,9 +592,9 @@ def tiny_root(tmp_path_factory):
     return root
 
 
-def _tiny_run(root, seed=2**33 + 5, control=False):
+def _tiny_run(root, cell="tiny.tiny", seed=2**33 + 5, control=False):
     out, err = io.StringIO(), io.StringIO()
-    rc = run_cell(Bench(root), "tiny.tiny", seed, TINY_SECONDS, control=control,
+    rc = run_cell(Bench(root), cell, seed, TINY_SECONDS, control=control,
                   require_tpu=False, compile_cache=False, out=out, err=err)
     assert rc == 0, err.getvalue()[-2000:]
     res = json.loads(out.getvalue().strip().splitlines()[-1])
@@ -459,20 +611,31 @@ def test_a_tiny_run_is_correct(tiny_root):
     assert res["device"]["platform"] == "cpu"
 
 
+def test_a_window_that_finishes_nothing_is_a_fault(tiny_root):
+    """Outputs of 32 or more tokens cannot finish in a 1-ms window: the
+    run cannot be judged and prints no result."""
+    out, err = io.StringIO(), io.StringIO()
+    with pytest.raises(Fault, match="no request finished"):
+        run_cell(Bench(tiny_root), "tiny.tiny", 7, 0.001, require_tpu=False,
+                 compile_cache=False, out=out, err=err)
+    assert not out.getvalue()
+
+
 def _served_to_the_end(seed, n=4, prompt_len=16, new=128):
     """Prompts and the tokens the program serves them, every request run
     to its end through the engine (no window, so no timing in it)."""
     import jax.numpy as jnp
 
-    from benchkit.model import make_params, program_config
+    from benchkit.model import make_params
     from repro.launch.serve import ModelServer
     from repro.serving.scheduler import ContinuousBatchingEngine, Request
 
     cfg = json.loads((BENCH / "configs" / "qwen2-0.5b.json").read_text())
     cfg.update(TINY)
-    server = ModelServer(program_config(cfg), n, max_len=1024,
+    fam = _qwen2()
+    server = ModelServer(fam.program_config(cfg), n, max_len=1024,
                          dtype=jnp.bfloat16)
-    server.params = make_params(_tiny_spec(), seed, jnp.bfloat16)
+    server.params = make_params(fam.leaves(_tiny_spec()), seed, jnp.bfloat16)
     eng = ContinuousBatchingEngine(server.step_fn, server.prefill_request,
                                    n, tenants={"a": 1.0}, use_kernel=True)
     rng = np.random.default_rng(seed)
@@ -497,8 +660,7 @@ def test_the_control_in_the_programs_place_is_not_correct(tiny_root):
 
 
 def test_the_int8_control_fails_the_tiny_limit():
-    from benchkit.reference import served_gaps
-
+    served_gaps = _qwen2().served_gaps
     for seed in (30, 31, 32):
         sound, ctl = served_gaps(_tiny_spec(), seed, _served_to_the_end(seed),
                                  control=True)
