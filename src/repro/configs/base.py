@@ -13,7 +13,7 @@ from dataclasses import dataclass, field
 
 @dataclass(frozen=True)
 class Block:
-    kind: str  # "attn" | "moe" | "mlstm" | "slstm" | "rglru"
+    kind: str  # "attn" | "moe" | "mlstm" | "slstm" | "rglru" | "mamba2"
     window: int = 0  # attn: sliding window (0 = full causal)
     rope_theta: float = 0.0  # attn: per-block rope base override (0 = cfg default)
 
@@ -38,6 +38,14 @@ class ModelConfig:
     sandwich_norms: bool = False  # gemma3 post-attn / post-ffn norms
     embed_scale: bool = False  # gemma x *= sqrt(d)
     tie_embeddings: bool = False
+    rms_eps: float = 1e-6  # every RMSNorm's epsilon
+    rope: bool = True  # False: attention without a position embedding
+    attn_scale: float = 0.0  # softmax scale of QK^T; 0 → 1/sqrt(head_dim)
+    # Granite's scalars: embeddings × embed_mult, each block's output ×
+    # residual_mult before it joins the residual, logits ÷ logits_div
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logits_div: float = 1.0
     # MoE
     n_experts: int = 0
     top_k: int = 0
@@ -53,6 +61,15 @@ class ModelConfig:
     # recurrent
     lru_width: int = 0
     xlstm_heads: int = 4
+    # Mamba-2 (SSD) mixer: heads of mamba_head_dim channels, a state of
+    # mamba_state per channel, n_groups 1 (B and C shared by every head),
+    # a causal depthwise conv of mamba_conv taps; sequences are scanned in
+    # chunks of mamba_chunk tokens
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
     # modality frontend stub: None | "audio" | "vision"
     frontend: str | None = None
     n_patches: int = 256  # vision stub: patch-embedding count
@@ -65,6 +82,15 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the conv: x, B and C (n_groups 1)."""
+        return self.mamba_inner + 2 * self.mamba_state
 
     @property
     def n_layers(self) -> int:
@@ -102,6 +128,10 @@ class ModelConfig:
                 hd_s = d // self.xlstm_heads
                 n += d + d * 4 * d + self.xlstm_heads * hd_s * 4 * hd_s + 4 * d + d * d
                 n += d + 3 * d * int(d * 4 / 3)
+            elif b.kind == "mamba2":
+                di, H, cd = self.mamba_inner, self.mamba_heads, self.mamba_conv_dim
+                n += d * (di + cd + H) + (self.mamba_conv + 1) * cd + 3 * H + di + di * d
+                n += 2 * d + 3 * d * self.d_ff  # norms + SwiGLU MLP
             elif b.kind == "rglru":
                 w = self.lru_width
                 n += d + 2 * d * w + 4 * w + 2 * w * w + 2 * w + w * d
@@ -140,6 +170,10 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         d_expert=32 if cfg.d_expert else 0,
         d_shared=64 if cfg.d_shared else 0,
         lru_width=n_heads * hd if cfg.lru_width else 0,
+        mamba_heads=8 if cfg.mamba_heads else 0,
+        mamba_head_dim=hd if cfg.mamba_heads else 0,
+        mamba_state=16 if cfg.mamba_heads else 0,
+        mamba_chunk=8 if cfg.mamba_heads else cfg.mamba_chunk,
         xlstm_heads=2,
         n_patches=8,
         max_seq_len=128,

@@ -23,6 +23,7 @@ ARCH_IDS = [
     "granite-moe-3b-a800m",
     "musicgen-medium",
     "internvl2-1b",
+    "granite-4.0-h-micro",
 ]
 
 _MODULES = {
@@ -36,6 +37,7 @@ _MODULES = {
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
     "musicgen-medium": "musicgen_medium",
     "internvl2-1b": "internvl2_1b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -59,11 +59,14 @@ class ModelServer:
         self._born = np.zeros((n_slots,), np.int64)  # call at row's prefill
         self._decode = jax.jit(self._decode_rows)
         self._reset_row = jax.jit(lambda c, row: reset_cache_row(cfg, c, row))
-        # chunked prefill where every block is full-context attention; a
-        # recurrent state, a window or MoE capacity makes a chunk another
-        # computation, so those stacks replay the prompt token by token
+        # chunked prefill where every block is full-context attention or
+        # Mamba-2 (whose state a chunk takes from the row and writes back);
+        # a window, MoE capacity or another recurrent block makes a chunk
+        # another computation, so those stacks replay the prompt token by
+        # token
         self.chunk = (min(PREFILL_CHUNK, max_len) if all(
-            b.kind == "attn" and b.window == 0 for b in cfg.blocks) else 0)
+            (b.kind == "attn" and b.window == 0) or b.kind == "mamba2"
+            for b in cfg.blocks) else 0)
         self._chunk_call = jax.jit(self._prefill_chunk, donate_argnums=4)
 
     def _decode_rows(self, params, tokens, positions, caches):
@@ -94,14 +97,16 @@ class ModelServer:
         return logits
 
     def prefill_request(self, req: Request):
-        """Row prefill.  Where every block is full-context attention the
-        prompt runs in calls of `chunk` tokens (the last one padded), one
-        compiled program for every length: each call attends the chunk's
-        queries over the row's ring entries and its own, causally by
-        position, and leaves the ring as that many decode calls in which
-        only this row is live would (its entries at the next slots, every
-        other row's entries there empty, the cursor and `_calls` advanced
-        by the valid tokens); only the last token's logits are formed.
+        """Row prefill.  Where every block is full-context attention or
+        Mamba-2 the prompt runs in calls of `chunk` tokens (the last one
+        padded), one compiled program for every length: each call attends
+        the chunk's queries over the row's ring entries and its own,
+        causally by position, scans the chunk from the row's Mamba-2 state
+        and writes the state back, and leaves the ring as that many decode
+        calls in which only this row is live would (its entries at the next
+        slots, every other row's entries there empty, the cursor and
+        `_calls` advanced by the valid tokens); only the last token's
+        logits are formed.
         Other stacks replay the prompt through one decode call per token
         (row-isolated caches make that exact).  Phase spans (under a
         profiler session): ``serve.prefill`` around ``serve.reset``,

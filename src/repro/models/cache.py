@@ -1,5 +1,5 @@
 """Decode-time state: KV caches (full + rolling sliding-window buffers) and
-recurrent states (mLSTM / sLSTM / RG-LRU).
+recurrent states (mLSTM / sLSTM / RG-LRU / Mamba-2).
 
 All caches are functional pytrees. KV caches carry explicit per-slot
 positions (``pos``, -1 = empty) so rolling buffers and continuous batching
@@ -189,4 +189,11 @@ def make_rglru_state(batch, width, conv_k=4, dtype=jnp.float32, conv_dtype=jnp.f
     return {
         "h": jnp.zeros((batch, width), dtype),
         "conv": jnp.zeros((batch, conv_k - 1, width), conv_dtype),  # causal-conv tail
+    }
+
+
+def make_mamba2_state(batch, n_heads, head_dim, d_state, conv_dim, conv_k=4, dtype=jnp.float32):
+    return {
+        "ssm": jnp.zeros((batch, n_heads, head_dim, d_state), dtype),  # per head P × N
+        "conv": jnp.zeros((batch, conv_k - 1, conv_dim), dtype),  # causal-conv tail
     }

@@ -87,20 +87,21 @@ def _online_softmax_block(carry, qk_scale, q, k, v, mask):
 
 
 def blockwise_attention(q, k, v, q_positions, kv_positions, *, window: int = 0,
-                        kv_block: int = 1024, causal: bool = True):
+                        kv_block: int = 1024, causal: bool = True, scale=None):
     """Flash-style attention with O(S) memory.
 
     q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd); positions give the absolute
     index of each row (so decode passes Sq=1 with its position).
     GQA: H is grouped onto KV heads by repetition (H % KV == 0).
     window > 0 ⇒ sliding-window (key kept iff 0 ≤ qpos-kpos < window).
+    ``scale`` multiplies QK^T (None: 1/sqrt(hd)).
     Returns (B, Sq, H, hd).
     """
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     assert H % KV == 0
     group = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qh = jnp.transpose(q, (0, 2, 1, 3))  # (B,H,Sq,hd)
     kh = jnp.transpose(k, (0, 2, 1, 3))  # (B,KV,Sk,hd)
     vh = jnp.transpose(v, (0, 2, 1, 3))
@@ -158,7 +159,8 @@ def blockwise_attention(q, k, v, q_positions, kv_positions, *, window: int = 0,
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)  # (B,Sq,H,hd)
 
 
-def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 0):
+def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 0,
+                           scale=None):
     """Decode-path attention (Sq == 1) against a (possibly sequence-sharded)
     KV cache, computed densely — no scan, no GQA head materialization.
 
@@ -167,13 +169,14 @@ def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 
     (parallel/sharding.py "seq_kv" rule) the SPMD partitioner turns the
     softmax max/sum reductions and the PV contraction into exactly the
     flash-decode log-sum-exp merge: each shard attends to its sequence slice
-    and partial results are combined with small all-reduces.
+    and partial results are combined with small all-reduces.  ``scale``
+    multiplies QK^T (None: 1/sqrt(hd)).
     """
     B, Sq, H, hd = q.shape
     _, C, KV, _ = k.shape
     assert Sq == 1 and H % KV == 0
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qg = q.reshape(B, KV, G, hd)  # (B,KV,G,hd) — GQA without repeating KV
     # flash-decode sharding contract: q is tiny — replicate it across the
     # model axis so the big KV keeps its *sequence* sharding; the partial
@@ -205,21 +208,23 @@ def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 
     return constrain(out, "batch", "seq", "heads")
 
 
-def chunk_attention(q, k, v, q_positions, ring_k, ring_v, ring_positions):
+def chunk_attention(q, k, v, q_positions, ring_k, ring_v, ring_positions, *,
+                    scale=None):
     """Prompt-chunk attention of one row: its T queries over its KV ring
     and the chunk's own keys, causal by position (a key is kept iff
     0 ≤ kpos ≤ qpos; position -1 marks an empty ring entry or padding).
     The ring row may hold only this sequence's earlier entries, so a chunk
     that starts the sequence (position 0) attends over its own keys alone
     and skips the ring.  Same numerics as `single_query_attention`: bf16
-    operands in the cache's dtype, f32 scores, softmax and accumulation.
+    operands in the cache's dtype, f32 scores, softmax and accumulation;
+    ``scale`` multiplies QK^T (None: 1/sqrt(hd)).
 
     q: (1, T, H, hd); k/v: (1, T, KV, hd); q_positions (1, T);
     ring_k/ring_v: (1, C, KV, hd); ring_positions (1, C)."""
     B, T, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qg = q.reshape(B, T, KV, G, hd).astype(ring_k.dtype)
     k, v = k.astype(ring_k.dtype), v.astype(ring_v.dtype)
 
@@ -267,8 +272,9 @@ def init_attention(key, cfg_layer, d_model, dtype):
     return p
 
 
-def qkv_project(p, x, positions, rope_theta=10000.0):
-    """x (B,S,D) → q (B,S,H,hd), k/v (B,S,KV,hd), RoPE applied."""
+def qkv_project(p, x, positions, rope_theta=10000.0, rope: bool = True):
+    """x (B,S,D) → q (B,S,H,hd), k/v (B,S,KV,hd), RoPE applied (unless
+    ``rope`` is False: attention without a position embedding)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -276,8 +282,9 @@ def qkv_project(p, x, positions, rope_theta=10000.0):
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    if rope:
+        q = apply_rope(q, positions, rope_theta)
+        k = apply_rope(k, positions, rope_theta)
     return q, k, v
 
 

@@ -9,7 +9,8 @@ Modes (static):
   train    — full-sequence forward, no caches, chunked-CE loss
   prefill  — full-sequence forward, writes KV caches / recurrent states
   decode   — one token per row against the caches
-  chunk    — one row's prompt chunk against that row's KV ring
+  chunk    — one row's prompt chunk against that row's KV ring and
+             recurrent state
 
 Frontend stubs (per assignment): "audio" consumes precomputed frame
 embeddings (B,S,D); "vision" prepends precomputed patch embeddings (B,P,D)
@@ -30,6 +31,7 @@ from .cache import (
     kv_cache_write_chunk,
     kv_cache_write_prefill,
     make_kv_cache,
+    make_mamba2_state,
     make_mlstm_state,
     make_rglru_state,
     make_slstm_state,
@@ -47,6 +49,7 @@ from .layers import (
     rms_norm,
     single_query_attention,
 )
+from .mamba2 import init_mamba2, mamba2_forward
 from .moe import init_moe, moe_forward
 from .rglru import init_rglru_block, rglru_block_forward, rglru_block_step
 from .xlstm import init_mlstm, init_slstm, mlstm_forward, slstm_forward
@@ -90,6 +93,14 @@ def init_block(key, spec: Block, cfg: ModelConfig, dtype):
         return init_mlstm(key, d, cfg.xlstm_heads, dtype)
     if spec.kind == "slstm":
         return init_slstm(key, d, cfg.xlstm_heads, dtype)
+    if spec.kind == "mamba2":
+        k1, k2 = jax.random.split(key)
+        return {
+            "ln1": init_rms_norm(d, dtype),
+            "mixer": init_mamba2(k1, d, cfg, dtype),
+            "ln2": init_rms_norm(d, dtype),
+            "mlp": MLP_INIT[cfg.mlp_kind](k2, d, cfg.d_ff, dtype),
+        }
     if spec.kind == "rglru":
         k1, k2 = jax.random.split(key)
         return {
@@ -139,6 +150,9 @@ def init_block_cache(spec: Block, cfg: ModelConfig, batch: int, capacity: int, d
         return make_slstm_state(batch, cfg.xlstm_heads, cfg.d_model // cfg.xlstm_heads)
     if spec.kind == "rglru":
         return make_rglru_state(batch, cfg.lru_width)
+    if spec.kind == "mamba2":
+        return make_mamba2_state(batch, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state,
+                                 cfg.mamba_conv_dim, cfg.mamba_conv)
     raise ValueError(spec.kind)
 
 
@@ -192,10 +206,11 @@ def keep_cache_rows(cfg: ModelConfig, new, old, live):
     """``new`` (after a decode step from ``old``) with the recurrent states of
     the rows where ``live`` (B,) is False taken back from ``old``.  A decode
     step advances every row's recurrent state; a row at position -1 writes
-    only an empty KV entry, so KV caches are taken from ``new`` as they are."""
+    only an empty KV entry, so KV caches are taken from ``new`` as they are,
+    and a Mamba-2 block leaves such a row's state as it was by itself."""
 
     def keep(spec, ax, n, o):
-        if spec.kind in ("attn", "moe"):
+        if spec.kind in ("attn", "moe", "mamba2"):
             return n
 
         def pick(a, b):
@@ -214,37 +229,40 @@ def block_forward(spec: Block, cfg: ModelConfig, p, x, positions, cache, mode: s
                   kv_block: int = 1024):
     """Returns (x', new_cache, aux). Residuals are applied inside."""
     aux = dict(ZERO_AUX)
+    eps = cfg.rms_eps
     if spec.kind in ("attn", "moe"):
         theta = spec.rope_theta or cfg.rope_theta
-        xn = rms_norm(x, p["ln1"], plus_one=cfg.norm_plus_one)
-        q, k, v = qkv_project(p["attn"], xn, positions, theta)
+        scale = cfg.attn_scale or None
+        xn = rms_norm(x, p["ln1"], eps, plus_one=cfg.norm_plus_one)
+        q, k, v = qkv_project(p["attn"], xn, positions, theta, rope=cfg.rope)
         q = constrain(q, "batch", "seq", "heads")
         new_cache = cache
         if mode == "train":
             ctx = blockwise_attention(q, k, v, positions, positions, window=spec.window,
-                                      kv_block=kv_block)
+                                      kv_block=kv_block, scale=scale)
         elif mode == "prefill":
             ctx = blockwise_attention(q, k, v, positions, positions, window=spec.window,
-                                      kv_block=kv_block)
+                                      kv_block=kv_block, scale=scale)
             new_cache = kv_cache_write_prefill(cache, k, v, positions)
         elif mode == "chunk":  # one row's prompt chunk over its ring entries
             # (``cache``: that row's view); the new entries are returned for
             # `prefill_chunk` to write
             ctx = chunk_attention(q, k, v, positions, cache["k"], cache["v"],
-                                  cache["pos"])
+                                  cache["pos"], scale=scale)
             new_cache = {"k": k, "v": v}
         else:  # decode — dense single-query path (scan-free; with the cache
             # sequence sharded over `model` the partitioner emits the
             # flash-decode LSE-merge all-reduces)
             new_cache = kv_cache_append(cache, k, v, positions)
             ctx = single_query_attention(
-                q, new_cache["k"], new_cache["v"], positions, new_cache["pos"], window=spec.window
+                q, new_cache["k"], new_cache["v"], positions, new_cache["pos"], window=spec.window,
+                scale=scale,
             )
         attn_out = attn_output(p["attn"], ctx)
         if cfg.sandwich_norms:
-            attn_out = rms_norm(attn_out, p["ln1b"], plus_one=cfg.norm_plus_one)
-        x = x + attn_out
-        xn2 = rms_norm(x, p["ln2"], plus_one=cfg.norm_plus_one)
+            attn_out = rms_norm(attn_out, p["ln1b"], eps, plus_one=cfg.norm_plus_one)
+        x = x + _residual(cfg, attn_out)
+        xn2 = rms_norm(x, p["ln2"], eps, plus_one=cfg.norm_plus_one)
         if spec.kind == "attn":
             ff = MLP_FWD[cfg.mlp_kind](p["mlp"], xn2)
         else:
@@ -253,8 +271,15 @@ def block_forward(spec: Block, cfg: ModelConfig, p, x, positions, cache, mode: s
             )
             aux = dict(ZERO_AUX, **aux)
         if cfg.sandwich_norms:
-            ff = rms_norm(ff, p["ln2b"], plus_one=cfg.norm_plus_one)
-        return x + ff, new_cache, aux
+            ff = rms_norm(ff, p["ln2b"], eps, plus_one=cfg.norm_plus_one)
+        return x + _residual(cfg, ff), new_cache, aux
+    if spec.kind == "mamba2":
+        # ``cache``: the rows' state (in mode "chunk", the one row's view)
+        mix, st = mamba2_forward(p["mixer"], cfg, rms_norm(x, p["ln1"], eps), positions,
+                                 cache, decode=mode == "decode")
+        x = x + _residual(cfg, mix)
+        ff = MLP_FWD[cfg.mlp_kind](p["mlp"], rms_norm(x, p["ln2"], eps))
+        return x + _residual(cfg, ff), st, aux
     if spec.kind == "mlstm":
         x, st = mlstm_forward(p, x, cfg.xlstm_heads, cache)
         return x, st, aux
@@ -266,9 +291,14 @@ def block_forward(spec: Block, cfg: ModelConfig, p, x, positions, cache, mode: s
             x, st = rglru_block_step(p["temporal"], x, cache)
         else:
             x, st = rglru_block_forward(p["temporal"], x, cache)
-        xn = rms_norm(x, p["ln2"], plus_one=cfg.norm_plus_one)
+        xn = rms_norm(x, p["ln2"], eps, plus_one=cfg.norm_plus_one)
         return x + MLP_FWD[cfg.mlp_kind](p["mlp"], xn), st, aux
     raise ValueError(spec.kind)
+
+
+def _residual(cfg: ModelConfig, y):
+    """A block's output as it joins the residual stream (× residual_mult)."""
+    return y if cfg.residual_mult == 1.0 else y * jnp.asarray(cfg.residual_mult, y.dtype)
 
 
 def _apply_unit(cfg, unit_params, x, positions, unit_cache, mode, kv_block=1024):
@@ -372,7 +402,7 @@ def forward_hidden(params, cfg: ModelConfig, x, positions, caches=None, mode="tr
         if caches is not None:
             new_caches["suffix"].append(nc)
 
-    h = rms_norm(x, params["final_ln"], plus_one=cfg.norm_plus_one)
+    h = rms_norm(x, params["final_ln"], cfg.rms_eps, plus_one=cfg.norm_plus_one)
     if new_caches is not None:
         new_caches = {k: v for k, v in new_caches.items() if v != []}
     return h, new_caches, aux_total
@@ -404,21 +434,35 @@ def embed_inputs(params, cfg: ModelConfig, batch):
         mask = jnp.ones((B, S), jnp.float32)
     if "loss_mask" in batch:
         mask = mask * batch["loss_mask"]
+    return _scale_embed(cfg, x), positions, mask
+
+
+def _scale_embed(cfg: ModelConfig, x):
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
-    return x, positions, mask
+    if cfg.embed_mult != 1.0:
+        x = x * jnp.asarray(cfg.embed_mult, x.dtype)
+    return x
 
 
 def lm_head(params, cfg: ModelConfig):
     return params["embed"].T if cfg.tie_embeddings else params["head"]
 
 
+def _logits(cfg: ModelConfig, logits):
+    """f32 logits, ÷ logits_div."""
+    logits = logits.astype(jnp.float32)
+    return logits if cfg.logits_div == 1.0 else logits / cfg.logits_div
+
+
 # ----------------------------------------------------------------- loss ----
 
 
-def chunked_ce_loss(h, head, labels, mask, chunk: int = 512, z_weight: float = 0.0):
+def chunked_ce_loss(h, head, labels, mask, chunk: int = 512, z_weight: float = 0.0,
+                    logits_div: float = 1.0):
     """Cross-entropy without materializing (B,S,V) logits: scan over sequence
-    chunks; fp32 statistics; vocab dim stays sharded (`vocab` → model axis)."""
+    chunks; fp32 statistics; vocab dim stays sharded (`vocab` → model axis);
+    logits ÷ ``logits_div``."""
     B, S, D = h.shape
     chunk = min(chunk, S)
     while S % chunk:
@@ -434,6 +478,8 @@ def chunked_ce_loss(h, head, labels, mask, chunk: int = 512, z_weight: float = 0
         lab = jax.lax.dynamic_slice(labels, (0, i * chunk), (B, chunk))
         msk = jax.lax.dynamic_slice(mask, (0, i * chunk), (B, chunk))
         logits = jnp.einsum("bsd,dv->bsv", hs, head.astype(hs.dtype)).astype(jnp.float32)
+        if logits_div != 1.0:
+            logits = logits / logits_div
         logits = constrain(logits, "batch", "seq", "vocab")
         lse = jax.nn.logsumexp(logits, axis=-1)
         ll = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
@@ -461,7 +507,8 @@ def train_loss(params, cfg: ModelConfig, batch, remat="dots", unroll_units=False
     h, _, aux = forward_hidden(params, cfg, x, positions, None, "train", remat=remat,
                                unroll_units=unroll_units, kv_block=kv_block)
     ce, cnt = chunked_ce_loss(h, lm_head(params, cfg), batch["labels"], mask,
-                              chunk=ce_chunk, z_weight=cfg.z_loss_weight)
+                              chunk=ce_chunk, z_weight=cfg.z_loss_weight,
+                              logits_div=cfg.logits_div)
     n_moe = max(1, sum(1 for b in cfg.blocks if b.kind == "moe"))
     lb = aux["lb_loss"] / n_moe
     loss = ce + 0.01 * lb + aux["z_loss"] / n_moe
@@ -484,44 +531,48 @@ def prefill(params, cfg: ModelConfig, batch, caches, unroll_units=False, kv_bloc
     h, caches, _ = forward_hidden(params, cfg, x, positions, caches, "prefill", remat="none",
                                   unroll_units=unroll_units, kv_block=kv_block)
     logits = jnp.einsum("bd,dv->bv", h[:, -1, :], lm_head(params, cfg).astype(h.dtype))
-    return logits.astype(jnp.float32), caches
+    return _logits(cfg, logits), caches
 
 
 def prefill_chunk(params, cfg: ModelConfig, tokens, positions, caches, row):
     """One prompt chunk of batch row ``row`` through a stack of
-    full-context attention blocks: tokens and positions (T,), position -1
-    marking padding.  Each block's queries attend over the row's ring
-    entries and the chunk's own (`layers.chunk_attention`); the valid
-    entries then land where as many decode calls would put them
-    (`cache.kv_cache_write_chunk`).  Returns (logits (V,) f32 of the last
-    valid token, caches)."""
-    x = params["embed"][tokens[None]]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
-    rows = _map_block_caches(
-        lambda spec, ax, c: {n: jax.lax.dynamic_slice_in_dim(c[n], row, 1, ax)
-                             for n in ("k", "v", "pos")}, cfg, caches)
+    full-context attention and Mamba-2 blocks: tokens and positions (T,),
+    position -1 marking padding (at the end).  Each attention block's
+    queries attend over the row's ring entries and the chunk's own
+    (`layers.chunk_attention`); the valid entries then land where as many
+    decode calls would put them (`cache.kv_cache_write_chunk`).  Each
+    Mamba-2 block scans the chunk from the row's state and writes the
+    state after the last valid token back at the row.  Returns (logits
+    (V,) f32 of the last valid token, caches)."""
+    x = _scale_embed(cfg, params["embed"][tokens[None]])
+
+    def view(spec, ax, c):
+        names = ("k", "v", "pos") if spec.kind == "attn" else tuple(c)
+        return {n: jax.lax.dynamic_slice_in_dim(c[n], row, 1, ax) for n in names}
+
+    def write(spec, ax, c, n):
+        if spec.kind == "attn":
+            return kv_cache_write_chunk(c, row, n["k"], n["v"], positions, ax)
+        return {k: jax.lax.dynamic_update_slice_in_dim(c[k], n[k].astype(c[k].dtype), row, ax)
+                for k in c}
+
+    rows = _map_block_caches(view, cfg, caches)
     h, new, _ = forward_hidden(params, cfg, x, positions[None], rows, "chunk",
                                remat="none")
     last = jnp.sum(positions >= 0) - 1
     h_last = jax.lax.dynamic_index_in_dim(h[0], last, keepdims=False)
     logits = jnp.einsum("d,dv->v", h_last, lm_head(params, cfg).astype(h.dtype))
-    caches = _map_block_caches(
-        lambda spec, ax, c, n: kv_cache_write_chunk(c, row, n["k"], n["v"],
-                                                    positions, ax),
-        cfg, caches, new)
-    return logits.astype(jnp.float32), caches
+    caches = _map_block_caches(write, cfg, caches, new)
+    return _logits(cfg, logits), caches
 
 
 def decode_step(params, cfg: ModelConfig, tokens, positions, caches, unroll_units=False):
     """tokens (B,1) int32, positions (B,1) int32 → (logits (B,V) f32, caches)."""
-    x = params["embed"][tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+    x = _scale_embed(cfg, params["embed"][tokens])
     h, caches, _ = forward_hidden(params, cfg, x, positions, caches, "decode", remat="none",
                                   unroll_units=unroll_units)
     logits = jnp.einsum("bd,dv->bv", h[:, -1, :], lm_head(params, cfg).astype(h.dtype))
-    return logits.astype(jnp.float32), caches
+    return _logits(cfg, logits), caches
 
 
 partial  # (linter guard)

@@ -46,6 +46,7 @@ def test_full_config_matches_assignment(arch):
         "granite-moe-3b-a800m": (32, 1536, 24, 8, None, 49155),
         "musicgen-medium": (48, 1536, 24, 24, 6144, 2048),
         "internvl2-1b": (24, 896, 14, 2, 4864, 151655),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }[arch]
     L, d, H, KV, dff, V = expected
     assert cfg.n_layers == L
@@ -60,6 +61,11 @@ def test_full_config_matches_assignment(arch):
         assert cfg.d_expert == 1408
     if arch == "granite-moe-3b-a800m":
         assert cfg.n_experts == 40 and cfg.top_k == 8 and cfg.d_expert == 512
+    if arch == "granite-4.0-h-micro":
+        assert [i for i, b in enumerate(cfg.blocks) if b.kind == "attn"] == [5, 15, 25, 35]
+        assert sum(b.kind == "mamba2" for b in cfg.blocks) == 36
+        assert (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state) == (64, 64, 128)
+        assert cfg.param_count() + cfg.d_model == 3_191_396_096  # + the final norm
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

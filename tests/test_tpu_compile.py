@@ -1,5 +1,6 @@
-"""Compile-for-chip rehearsals: the served Pallas kernels and a full-width
-qwen2-0.5b decode step, compiled without interpret mode for a described
+"""Compile-for-chip rehearsals: the served Pallas kernels, a full-width
+qwen2-0.5b decode step and granite-4.0-h-micro's decode and prompt-chunk
+programs, compiled without interpret mode for a described
 TPU v5e chip at the widths `chip_smoke.py` serves.  Nothing runs, so these
 say nothing about results or times; they catch what only the chip's
 compiler refuses (Mosaic layouts, unsupported ops, memory) at no chip
@@ -157,6 +158,45 @@ def test_qwen2_prefill_chunk_fits_beside_decode_on_v5e(chip):
     assert m.alias_size_in_bytes >= ring > 1.6e9  # no second ring
     assert m.temp_size_in_bytes < ring / 2
     assert prefill <= decode, (prefill, decode)
+
+
+def test_granite_hybrid_programs_fit_v5e(chip):
+    """granite-4.0-h-micro at its published size (bf16 weights, 16 slots,
+    an 8,192-entry ring in the 4 attention layers, f32 Mamba-2 state in
+    the 36 others): the decode program (with the rows' state kept as
+    `ModelServer` keeps it) fits one chip beside its new caches, and the
+    prompt-chunk program updates the donated caches in place."""
+    from repro.configs.registry import get_config
+    from repro.models.transformer import (decode_step, init_caches,
+                                          init_params, keep_cache_rows,
+                                          prefill_chunk)
+
+    cfg = get_config("granite-4.0-h-micro")
+    place = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), t)
+    params = place(jax.eval_shape(
+        lambda k: init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(
+        lambda: init_caches(cfg, 16, 8192, jnp.bfloat16)))
+    size = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))
+    assert size(params) == 2 * 3_191_396_096
+    assert 2.3e9 < size(caches) < 2.4e9  # 1.07 GB ring + 1.24 GB state
+    tok, pos = _shapes(chip, ((16, 1), jnp.int32), ((16, 1), jnp.int32))
+    chunk, row = _shapes(chip, ((256,), jnp.int32), ((), jnp.int32))
+
+    def decode(p, t, q, c):
+        logits, new = decode_step(p, cfg, t, q, c)
+        return logits, keep_cache_rows(cfg, new, c, q[:, 0] >= 0)
+
+    m = jax.jit(decode).lower(params, tok, pos, caches).compile().memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < 12e9, total
+    m = jax.jit(lambda p, t, q, r, c: prefill_chunk(p, cfg, t, q, c, r),
+                donate_argnums=4).lower(params, chunk, chunk, row,
+                                        caches).compile().memory_analysis()
+    assert m.alias_size_in_bytes >= size(caches)  # no second copy
+    assert m.temp_size_in_bytes < 1e9
 
 
 def test_served_megastep_compiles_for_v5e(chip, monkeypatch):
