@@ -22,15 +22,33 @@ from ..models.transformer import (
     decode_step,
     init_caches,
     init_params,
+    join_caches,
     keep_cache_rows,
     prefill_chunk,
     reset_cache_row,
+    split_caches,
 )
 from ..obs.trace import count, span
 from ..serving.scheduler import ContinuousBatchingEngine, Request
 from .compile_cache import setup_compile_cache
 
 PREFILL_CHUNK = 256  # prompt tokens per prefill call (at most max_len)
+
+
+def decode_program(cfg: ModelConfig):
+    """`ModelServer`'s jitted decode call: (params, tokens (B,1), positions
+    (B,1), rings, states) → (logits, caches), where (rings, states) is
+    `split_caches` of the caches.  The rings are donated, so each call
+    writes its one slot in place; the recurrent states are not (a layer
+    scan's stacked output cannot take the buffer it reads, so a donated
+    state would be copied whole), and rows at position -1 keep theirs."""
+
+    def _decode_rows(params, tokens, positions, rings, states):
+        caches = join_caches(cfg, rings, states)
+        logits, new = decode_step(params, cfg, tokens, positions, caches)
+        return logits, keep_cache_rows(cfg, new, caches, positions[:, 0] >= 0)
+
+    return jax.jit(_decode_rows, donate_argnums=3)
 
 
 class ModelServer:
@@ -43,7 +61,10 @@ class ModelServer:
     new request starts from a reset row, and a request's entries stay in
     the ring for ``max_len`` calls: a call that would overwrite a live
     request's oldest entry raises instead.  A prompt of n tokens counts as
-    n calls (`prefill_request`)."""
+    n calls (`prefill_request`).  Each call takes the KV rings of
+    ``caches`` donated (the row reset and the prompt chunks take them all):
+    read ``caches`` again after a call, never through a reference kept
+    from before it."""
 
     def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int = 128,
                  seed: int = 0, dtype=jnp.float32):
@@ -57,8 +78,9 @@ class ModelServer:
         self.row_pos = np.zeros((n_slots,), np.int32)
         self._calls = 0  # decode calls so far == every cache's ring cursor
         self._born = np.zeros((n_slots,), np.int64)  # call at row's prefill
-        self._decode = jax.jit(self._decode_rows)
-        self._reset_row = jax.jit(lambda c, row: reset_cache_row(cfg, c, row))
+        self._decode = decode_program(cfg)
+        self._reset_row = jax.jit(lambda c, row: reset_cache_row(cfg, c, row),
+                                  donate_argnums=0)  # in place
         # chunked prefill where every block is full-context attention or
         # Mamba-2 (whose state a chunk takes from the row and writes back);
         # a window, MoE capacity or another recurrent block makes a chunk
@@ -68,10 +90,6 @@ class ModelServer:
             (b.kind == "attn" and b.window == 0) or b.kind == "mamba2"
             for b in cfg.blocks) else 0)
         self._chunk_call = jax.jit(self._prefill_chunk, donate_argnums=4)
-
-    def _decode_rows(self, params, tokens, positions, caches):
-        logits, new = decode_step(params, self.cfg, tokens, positions, caches)
-        return logits, keep_cache_rows(self.cfg, new, caches, positions[:, 0] >= 0)
 
     def _prefill_chunk(self, params, tokens, positions, row, caches):
         return prefill_chunk(params, self.cfg, tokens, positions, caches, row)
@@ -92,7 +110,7 @@ class ModelServer:
         # `self.tokens` changes before this asynchronous call has run
         logits, self.caches = self._decode(
             self.params, jnp.asarray(self.tokens.copy()), jnp.asarray(pos),
-            self.caches)
+            *split_caches(self.cfg, self.caches))
         self._calls += 1
         return logits
 

@@ -51,6 +51,34 @@ def kv_cache_write_prefill(cache, k, v, positions):
     return {"k": kc, "v": vc, "pos": pc, "ptr": cache["ptr"] + S}
 
 
+def seq_shard_axes() -> list[str]:
+    """The mesh axes that the active ``seq_kv`` rule spreads a KV ring's
+    sequence axis over (empty without a mesh or such a rule)."""
+    from ..parallel.sharding import current_rules, mesh_axes
+
+    axes = mesh_axes()
+    rule = current_rules().get("seq_kv")
+    rule = (rule,) if isinstance(rule, str) else (rule or ())
+    return [a for a in rule if a in axes]
+
+
+def kv_cache_write_slot(cache, new, ax: int = 0):
+    """Write one decode call's entries into a ring that is not sequence
+    sharded: ``new`` holds k/v (…, B, 1, KV, hd), already in the cache's
+    dtype, and pos (…, B, 1), with the batch at axis ``ax`` (1 for a
+    unit-stacked cache).  One dynamic-update-slice per leaf at slot
+    ptr mod capacity, in place when the cache is donated; the cursor
+    advances by one.  The same ring as `kv_cache_append` leaves."""
+    C = cache["pos"].shape[ax + 1]
+    slot = jnp.ravel(cache["ptr"])[0] % C  # every layer's cursor is the same
+    z = jnp.zeros((), jnp.int32)
+    out = {"ptr": cache["ptr"] + 1}
+    for name in ("k", "v", "pos"):
+        at = (z,) * (ax + 1) + (slot,) + (z,) * (cache[name].ndim - ax - 2)
+        out[name] = jax.lax.dynamic_update_slice(cache[name], new[name], at)
+    return out
+
+
 def kv_cache_append(cache, k, v, positions, ring_write: bool = False):
     """Append one token per row (decode). k/v (B,1,KV,hd), positions (B,1).
     Rolling ring buffer: every row writes slot = ptr mod capacity (scalar —
@@ -70,15 +98,9 @@ def kv_cache_append(cache, k, v, positions, ring_write: bool = False):
     C = cache["k"].shape[1]
     slot = cache["ptr"] % C  # scalar
     if ring_write:
-        from ..parallel.sharding import current_rules, mesh_axes, spec_for
-
-        axes = mesh_axes()
-        rule = current_rules().get("seq_kv")
-        rule = (rule,) if isinstance(rule, str) else (rule or ())
-        shard_axes = [a for a in rule if a in axes]
+        shard_axes = seq_shard_axes()
         if shard_axes:
             return _ring_write_sharded(cache, k, v, positions, slot, shard_axes)
-    z = jnp.zeros((), jnp.int32)
     hit = (jnp.arange(C, dtype=jnp.int32) == slot)  # (C,)
     kc = jnp.where(hit[None, :, None, None], k.astype(cache["k"].dtype), cache["k"])
     vc = jnp.where(hit[None, :, None, None], v.astype(cache["v"].dtype), cache["v"])

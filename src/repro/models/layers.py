@@ -160,7 +160,7 @@ def blockwise_attention(q, k, v, q_positions, kv_positions, *, window: int = 0,
 
 
 def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 0,
-                           scale=None):
+                           scale=None, new=None, skip=None):
     """Decode-path attention (Sq == 1) against a (possibly sequence-sharded)
     KV cache, computed densely — no scan, no GQA head materialization.
 
@@ -171,6 +171,12 @@ def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 
     flash-decode log-sum-exp merge: each shard attends to its sequence slice
     and partial results are combined with small all-reduces.  ``scale``
     multiplies QK^T (None: 1/sqrt(hd)).
+
+    ``new`` (k, v), each (B, 1, KV, hd) in the cache's dtype: the query's
+    own entry, attended beside the cache (kept where the query's position
+    is ≥ 0), with cache slot ``skip`` (the one that entry is about to
+    overwrite) left out — the same entries as after the write, with no
+    new cache formed.
     """
     B, Sq, H, hd = q.shape
     _, C, KV, _ = k.shape
@@ -196,13 +202,26 @@ def single_query_attention(q, k, v, q_positions, kv_positions, *, window: int = 
     mask = (kv_positions[:, None, None, :] >= 0) & (dpos >= 0)
     if window > 0:
         mask &= dpos < window
+    if skip is not None:
+        mask &= jnp.arange(C, dtype=jnp.int32) != skip
     s = jnp.where(mask, s, -jnp.inf)
     m = jnp.max(s, axis=-1, keepdims=True)
+    if new is not None:
+        kn, vn = new[0][:, 0], new[1][:, 0]  # (B, KV, hd)
+        own = q_positions[:, :, None, None] >= 0  # (B, 1, 1, 1)
+        sn = jnp.einsum("bkgd,bkd->bkg", qg.astype(kn.dtype), kn,
+                        preferred_element_type=jnp.float32)[..., None] * scale
+        m = jnp.maximum(m, sn)
     m = jnp.where(jnp.isfinite(m), m, 0.0)
     p = jnp.where(mask, jnp.exp(s - m), 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     ctx = jnp.einsum("bkgc,bckd->bkgd", p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32) / jnp.maximum(l, 1e-30)
+                     preferred_element_type=jnp.float32)
+    if new is not None:
+        pn = jnp.where(own, jnp.exp(sn - m), 0.0)
+        l = l + pn
+        ctx = ctx + pn.astype(vn.dtype).astype(jnp.float32) * vn[:, :, None, :]
+    ctx = ctx / jnp.maximum(l, 1e-30)
     out = ctx.reshape(B, 1, H, hd).astype(q.dtype)
     # hand the o-projection a head-sharded ctx (reduce-scatter, not all-reduce)
     return constrain(out, "batch", "seq", "heads")

@@ -30,11 +30,13 @@ from .cache import (
     kv_cache_append,
     kv_cache_write_chunk,
     kv_cache_write_prefill,
+    kv_cache_write_slot,
     make_kv_cache,
     make_mamba2_state,
     make_mlstm_state,
     make_rglru_state,
     make_slstm_state,
+    seq_shard_axes,
 )
 from .layers import (
     MLP_FWD,
@@ -202,6 +204,20 @@ def reset_cache_row(cfg: ModelConfig, caches, row):
     return _map_block_caches(reset, cfg, caches)
 
 
+def split_caches(cfg: ModelConfig, caches):
+    """(rings, states): the KV rings of ``caches`` (attention and MoE
+    blocks) and the other blocks' recurrent states, each tree with an
+    empty dict in the other's place; `join_caches` undoes it."""
+    kv = lambda spec: spec.kind in ("attn", "moe")
+    return (_map_block_caches(lambda s, ax, c: c if kv(s) else {}, cfg, caches),
+            _map_block_caches(lambda s, ax, c: {} if kv(s) else c, cfg, caches))
+
+
+def join_caches(cfg: ModelConfig, rings, states):
+    """The caches that `split_caches` split into ``rings`` and ``states``."""
+    return _map_block_caches(lambda s, ax, r, st: r or st, cfg, rings, states)
+
+
 def keep_cache_rows(cfg: ModelConfig, new, old, live):
     """``new`` (after a decode step from ``old``) with the recurrent states of
     the rows where ``live`` (B,) is False taken back from ``old``.  A decode
@@ -250,13 +266,23 @@ def block_forward(spec: Block, cfg: ModelConfig, p, x, positions, cache, mode: s
             ctx = chunk_attention(q, k, v, positions, cache["k"], cache["v"],
                                   cache["pos"], scale=scale)
             new_cache = {"k": k, "v": v}
-        else:  # decode — dense single-query path (scan-free; with the cache
-            # sequence sharded over `model` the partitioner emits the
-            # flash-decode LSE-merge all-reduces)
+        elif seq_shard_axes():  # decode over a sequence-sharded ring — dense
+            # single-query path (scan-free; the partitioner emits the
+            # flash-decode LSE-merge all-reduces) after a one-hot write: a
+            # dynamic-update-slice on that axis rematerializes the cache
             new_cache = kv_cache_append(cache, k, v, positions)
             ctx = single_query_attention(
                 q, new_cache["k"], new_cache["v"], positions, new_cache["pos"], window=spec.window,
                 scale=scale,
+            )
+        else:  # decode: the ring as it stood beside the new entry, which is
+            # returned for `decode_step` to write in place after the scan
+            dt = cache["k"].dtype
+            new_cache = {"k": k.astype(dt), "v": v.astype(dt), "pos": positions}
+            ctx = single_query_attention(
+                q, cache["k"], cache["v"], positions, cache["pos"], window=spec.window,
+                scale=scale, new=(new_cache["k"], new_cache["v"]),
+                skip=cache["ptr"] % cache["k"].shape[1],
             )
         attn_out = attn_output(p["attn"], ctx)
         if cfg.sandwich_norms:
@@ -567,12 +593,20 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, positions, caches, row):
 
 
 def decode_step(params, cfg: ModelConfig, tokens, positions, caches, unroll_units=False):
-    """tokens (B,1) int32, positions (B,1) int32 → (logits (B,V) f32, caches)."""
+    """tokens (B,1) int32, positions (B,1) int32 → (logits (B,V) f32, caches).
+
+    A ring that is not sequence sharded comes back from the layers as its
+    new entries alone, and each leaf takes them with one write at the slot
+    (`cache.kv_cache_write_slot`), in place where the caches are donated."""
     x = _scale_embed(cfg, params["embed"][tokens])
-    h, caches, _ = forward_hidden(params, cfg, x, positions, caches, "decode", remat="none",
-                                  unroll_units=unroll_units)
+    h, new, _ = forward_hidden(params, cfg, x, positions, caches, "decode", remat="none",
+                               unroll_units=unroll_units)
+    if not seq_shard_axes():
+        new = _map_block_caches(
+            lambda s, ax, c, n: kv_cache_write_slot(c, n, ax) if s.kind in ("attn", "moe") else n,
+            cfg, caches, new)
     logits = jnp.einsum("bd,dv->bv", h[:, -1, :], lm_head(params, cfg).astype(h.dtype))
-    return _logits(cfg, logits), caches
+    return _logits(cfg, logits), new
 
 
 partial  # (linter guard)

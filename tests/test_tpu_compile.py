@@ -1,6 +1,7 @@
 """Compile-for-chip rehearsals: the served Pallas kernels, a full-width
-qwen2-0.5b decode step and granite-4.0-h-micro's decode and prompt-chunk
-programs, compiled without interpret mode for a described
+qwen2-0.5b decode step, `ModelServer`'s decode programs (KV rings donated
+and written one slot in place) and granite-4.0-h-micro's prompt-chunk
+program, compiled without interpret mode for a described
 TPU v5e chip at the widths `chip_smoke.py` serves.  Nothing runs, so these
 say nothing about results or times; they catch what only the chip's
 compiler refuses (Mosaic layouts, unsupported ops, memory) at no chip
@@ -11,6 +12,7 @@ written for a described chip cannot be read back without one."""
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +43,61 @@ def chip():
 
 def _shapes(sharding, *specs):
     return [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in specs]
+
+
+def _served(sharding, arch):
+    """(cfg, caches, compiled): `ModelServer`'s decode program for ``arch``
+    at the benchmark's widths (bf16, 16 slots, an 8,192-entry ring) as the
+    server compiles it, KV rings donated, for the described chip."""
+    from repro.configs.registry import get_config
+    from repro.launch.serve import decode_program
+    from repro.models.transformer import init_caches, init_params, split_caches
+
+    cfg = get_config(arch)
+    place = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), t)
+    params = place(jax.eval_shape(
+        lambda k: init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(
+        lambda: init_caches(cfg, 16, 8192, jnp.bfloat16)))
+    tok, pos = _shapes(sharding, ((16, 1), jnp.int32), ((16, 1), jnp.int32))
+    compiled = decode_program(cfg).lower(
+        params, tok, pos, *split_caches(cfg, caches)).compile()
+    return cfg, caches, compiled
+
+
+@pytest.fixture(scope="module")
+def served_qwen2(chip):
+    return _served(chip, "qwen2-0.5b")
+
+
+@pytest.fixture(scope="module")
+def served_granite(chip):
+    return _served(chip, "granite-4.0-h-micro")
+
+
+_INSTR = re.compile(r"\s+(ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)")
+
+
+def _hlo(text):
+    """{computation: [instruction]} of an optimized HLO module's text; an
+    instruction is a dict of its name, dims (array outputs only), opcode,
+    operand names, called computation and whether it is the ROOT."""
+    comps, cur = {}, []
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            head = line.split(" (")[0].split()
+            cur = comps.setdefault("ENTRY" if head[0] == "ENTRY" else head[-1].lstrip("%"), [])
+            continue
+        m = _INSTR.match(line)
+        if m:
+            calls = re.search(r"calls=%?([\w.\-]+)", line)
+            cur.append(dict(root=bool(m.group(1)), name=m.group(2),
+                            dims=tuple(int(d) for d in m.group(3).split(",") if d),
+                            op=m.group(4), calls=calls and calls.group(1),
+                            args=[a.strip().lstrip("%") for a in re.sub(
+                                r"/\*.*?\*/", "", m.group(5)).split(",")]))
+    return comps
 
 
 def _qos(sharding):
@@ -160,35 +217,80 @@ def test_qwen2_prefill_chunk_fits_beside_decode_on_v5e(chip):
     assert prefill <= decode, (prefill, decode)
 
 
-def test_granite_hybrid_programs_fit_v5e(chip):
+def test_served_qwen2_decode_updates_the_ring_in_place(served_qwen2):
+    """`ModelServer`'s qwen2-0.5b decode program takes the donated ring
+    and returns it as its output (one 1.62 GB ring, not two), with next to
+    no scratch."""
+    _, caches, compiled = served_qwen2
+    ring = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= ring > 1.6e9
+    assert m.temp_size_in_bytes < 0.1e9, m.temp_size_in_bytes
+
+
+def test_served_qwen2_decode_writes_one_slot_of_the_ring(served_qwen2):
+    """No op of that program outputs a whole stacked ring (k, v or pos)
+    but a dynamic-update-slice of one slot (alone or as a fusion's root):
+    no one-hot select over the ring, no copy of it."""
+    _, caches, compiled = served_qwen2
+    ring = caches["units"]["b0"]
+    shapes = {ring[n].shape for n in ("k", "v", "pos")}
+    comps = _hlo(compiled.as_text())
+    dims = {i["name"]: i["dims"] for ops in comps.values() for i in ops}
+
+    def one_slot(i):
+        if i["op"] == "fusion":
+            (root,) = [r for r in comps[i["calls"]] if r["root"]]
+            return one_slot(root)
+        if i["op"] != "dynamic-update-slice":
+            return False
+        upd = dims[i["args"][1]]
+        return upd[2] == 1 and upd[:2] + upd[3:] == i["dims"][:2] + i["dims"][3:]
+
+    writes = 0
+    for comp, ops in comps.items():
+        for i in ops:
+            if i["dims"] not in shapes or i["op"] in ("parameter", "get-tuple-element",
+                                                       "bitcast"):
+                continue
+            assert one_slot(i), (comp, i["name"], i["op"])
+            writes += 1
+    assert writes >= 3  # k, v and pos
+
+
+def test_served_granite_decode_copies_no_state(served_granite):
+    """granite-4.0-h-micro's decode program as `ModelServer` compiles it:
+    the rings donated and the f32 Mamba-2 state not, so no entry-level copy
+    of the state and next to no scratch."""
+    _, caches, compiled = served_granite
+    state = {x.shape for x in jax.tree.leaves(caches) if x.dtype == jnp.float32}
+    assert (4, 16, 64, 64, 128) in state
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.1e9, m.temp_size_in_bytes
+    copies = [i["name"] for i in _hlo(compiled.as_text())["ENTRY"]
+              if i["op"].startswith("copy") and i["dims"] in state]
+    assert not copies, copies
+
+
+def test_granite_hybrid_programs_fit_v5e(chip, served_granite):
     """granite-4.0-h-micro at its published size (bf16 weights, 16 slots,
     an 8,192-entry ring in the 4 attention layers, f32 Mamba-2 state in
-    the 36 others): the decode program (with the rows' state kept as
-    `ModelServer` keeps it) fits one chip beside its new caches, and the
-    prompt-chunk program updates the donated caches in place."""
-    from repro.configs.registry import get_config
-    from repro.models.transformer import (decode_step, init_caches,
-                                          init_params, keep_cache_rows,
-                                          prefill_chunk)
+    the 36 others): the decode program as `ModelServer` compiles it fits
+    one chip beside its new state, and the prompt-chunk program updates the
+    donated caches in place."""
+    from repro.models.transformer import init_params, prefill_chunk
 
-    cfg = get_config("granite-4.0-h-micro")
+    cfg, caches, compiled = served_granite
     place = lambda t: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), t)
     params = place(jax.eval_shape(
         lambda k: init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
-    caches = place(jax.eval_shape(
-        lambda: init_caches(cfg, 16, 8192, jnp.bfloat16)))
     size = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))
     assert size(params) == 2 * 3_191_396_096
     assert 2.3e9 < size(caches) < 2.4e9  # 1.07 GB ring + 1.24 GB state
-    tok, pos = _shapes(chip, ((16, 1), jnp.int32), ((16, 1), jnp.int32))
     chunk, row = _shapes(chip, ((256,), jnp.int32), ((), jnp.int32))
 
-    def decode(p, t, q, c):
-        logits, new = decode_step(p, cfg, t, q, c)
-        return logits, keep_cache_rows(cfg, new, c, q[:, 0] >= 0)
-
-    m = jax.jit(decode).lower(params, tok, pos, caches).compile().memory_analysis()
+    m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total < 12e9, total
