@@ -23,11 +23,11 @@ demand had to be free up front).  With ``chunked_prefill=(chunk, budget)``:
 
 Run:  PYTHONPATH=src python examples/serve_longprompt.py
 
-Throughput/utilization vs the worst-case up-front mode at equal HBM is
-measured in `benchmarks/serving_bench.py` (chunked-prefill section;
-BENCH_PR5.json).  The Pallas kernel for real models (ragged blockwise
-flash-prefill, causal chunk attention + in-pass pool writeback) is
-`kernels/paged_prefill` — oracle-bit-exact, see tests/test_paged_prefill.py.
+Pool utilization above the worst-case up-front mode's at equal HBM is
+held by tests/test_chunked_prefill.py.  The Pallas kernel for real models
+(ragged blockwise flash-prefill, causal chunk attention + in-pass pool
+writeback) is `kernels/paged_prefill` — oracle-bit-exact, see
+tests/test_paged_prefill.py.
 """
 
 import numpy as np

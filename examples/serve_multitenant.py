@@ -69,11 +69,10 @@ QoS admission → TWA slot assignment → decode+sample → completion) as one
 jitted `lax.scan` over a donated on-device EngineState pytree
 (`serving.engine_state`), so the host syncs once per K decoded tokens
 instead of once per token.  Semantics are property-tested identical to K
-sequential ``step()`` calls (tests/test_megastep.py); throughput vs K is
-measured in `benchmarks/serving_bench.py` (≥5× at K=32 on CPU).  Custom
-in-graph models plug in via ``token_fn``/``admit_fn`` — see
-`engine_state.paged_attn_token_fn` for paged decode attention with
-in-graph prompt prefill.
+sequential ``step()`` calls (tests/test_megastep.py).  Custom in-graph
+models plug in via ``token_fn``/``admit_fn`` — see
+`engine_state.paged_pool_token_fn` for paged decode attention over the
+block pool with in-graph prompt prefill.
 
 Continuous chunked prefill (``chunked_prefill=(chunk, budget)``): long
 prompts stream through the engine in per-round chunks with INCREMENTAL
@@ -89,8 +88,7 @@ slot and each request's worst-case block demand in strict FCFS order
 sequence actually holds (`engine_state.paged_pool_token_fn`;
 `kernels/paged_decode` on TPU), preemption/completion post the blocks
 back, and `telemetry()` exposes the kv_blocks_free / kv_blocks_live
-gauges.  Mixed-length throughput vs the dense rings at equal HBM is
-measured in `benchmarks/serving_bench.py` (≥2× tokens/s on the CPU toy).
+gauges.
 """
 
 import sys

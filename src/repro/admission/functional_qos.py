@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from ..core.functional import (
     _sdist,
     live_fifo_rank,
-    live_fifo_rank_pairwise,
     segment_counts,
     twa_hash_u32,
 )
@@ -155,21 +154,15 @@ def qos_expire(state: QoSState, tenant_ids: jax.Array, alive: jax.Array,
 
 
 def qos_admit(state: QoSState, tenant_ids: jax.Array, tickets: jax.Array,
-              alive: jax.Array, *, pairwise_rank: bool = False):
+              alive: jax.Array):
     """Tombstone-transparent weighted-FCFS admission over the live backlog:
-    row admitted ⇔ live_fifo_rank < avail[tenant].  Consumes the units.
-    Returns ``(state', admitted)``.
-
-    ``pairwise_rank=True`` routes through the retained O(N²) rank path —
-    benchmark baseline only; the default is the O(N·S/block) blocked
-    prefix (`core.functional.live_fifo_rank`)."""
+    row admitted ⇔ live_fifo_rank < avail[tenant] (the O(N·S/block)
+    blocked prefix, `core.functional.live_fifo_rank`).  Consumes the
+    units.  Returns ``(state', admitted)``."""
     tenant_ids = jnp.asarray(tenant_ids, jnp.int32)
     S = state.ticket.shape[0]
     tickets = jnp.asarray(tickets, jnp.uint32)
-    if pairwise_rank:
-        rank = live_fifo_rank_pairwise(tenant_ids, tickets, alive)
-    else:
-        rank = live_fifo_rank(tenant_ids, tickets, alive, S)
+    rank = live_fifo_rank(tenant_ids, tickets, alive, S)
     admitted = alive & (rank < avail(state)[tenant_ids])
     spent = segment_counts(tenant_ids, admitted, S)
     return state._replace(consumed=state.consumed + spent), admitted
@@ -321,8 +314,8 @@ def block_gate(admitted: jax.Array, demand: jax.Array, key: jax.Array,
     sequences write, so reservations overlap in time — but it bounds
     aggregate outstanding demand: an overcommitted pool degenerates into
     the safety chain serializing the endgame (one funded slot at a time),
-    which costs more rounds than the extra residency buys (measured in
-    `benchmarks/serving_bench.run_longprompt`).  ``commit_bootstrap``
+    which costs more rounds than the extra residency buys.
+    ``commit_bootstrap``
     (the "pool is uncommitted" flag) exempts the FCFS-FIRST candidate
     from the watermark so a request larger than W is still served — it
     waits, strict no-bypass, until the pool drains, then runs alone
@@ -412,22 +405,20 @@ def qos_scan_round(state: QoSState, tenant_ids: jax.Array,
 
 def qos_round(state: QoSState, tenant_ids: jax.Array, tickets: jax.Array,
               alive: jax.Array, deadlines: jax.Array, now, free_units,
-              max_units: int, *, pairwise_rank: bool = False):
+              max_units: int):
     """One whole multi-tenant admission round as a single jit-able pass:
     expire → replenish (weighted) → admit (tombstone-transparent FCFS) →
     reclaim stranded credit.  Returns
     ``(state', admitted, expired, leftover_units)``.
 
     This is the oracle semantics for the fused Pallas kernel
-    (`kernels.qos_admission.qos_round_fused`); ``pairwise_rank=True``
-    selects the retained O(N²) rank baseline (benchmarks only)."""
+    (`kernels.qos_admission.qos_round_fused`)."""
     tenant_ids = jnp.asarray(tenant_ids, jnp.int32)
     state, alive, expired = qos_expire(state, tenant_ids, alive, deadlines, now)
     S = state.ticket.shape[0]
     depth = segment_counts(tenant_ids, alive, S, dtype=jnp.int32)
     state, _, leftover = qos_replenish(state, free_units, depth, max_units)
-    state, admitted = qos_admit(state, tenant_ids, tickets, alive,
-                                pairwise_rank=pairwise_rank)
+    state, admitted = qos_admit(state, tenant_ids, tickets, alive)
     depth_after = depth - segment_counts(tenant_ids, admitted, S,
                                          dtype=jnp.int32)
     state, reclaimed = qos_reclaim(state, depth_after)

@@ -1,6 +1,6 @@
 """Config schema: a model is a (prefix, repeating-unit × N, suffix) stack of
 heterogeneous blocks.  The repeating unit is lax.scan'ed (HLO size stays O(1)
-in depth — compile-time critical for the 512-device dry-runs); prefix/suffix
+in depth, so compile time does not grow with it); prefix/suffix
 hold non-repeating layers (e.g. DeepSeekMoE's dense layer 0, RecurrentGemma's
 ragged tail).
 """
@@ -55,8 +55,7 @@ class ModelConfig:
     capacity_factor: float = 1.25
     # EP padding: expert tensors padded to this count so the expert dim
     # divides the model axis (granite: 40 → 48); router stays at n_experts,
-    # padded experts are dead weight (counted in the HLO-vs-model FLOPs
-    # ratio, see EXPERIMENTS.md).
+    # padded experts are dead weight (compiled FLOPs exceed the model's).
     n_experts_pad: int = 0
     # recurrent
     lru_width: int = 0
@@ -101,8 +100,7 @@ class ModelConfig:
         return list(self.prefix) + list(self.unit) * self.num_units + list(self.suffix)
 
     def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks + head); used for
-        MODEL_FLOPS = 6·N·D in the roofline."""
+        """Analytic parameter count (embedding + blocks + head)."""
         d, hd = self.d_model, self.hd
         n = self.vocab * d  # embedding
         if not self.tie_embeddings:

@@ -78,7 +78,3 @@ def shapes_for(arch: str):
     if arch in SUBQUADRATIC:
         names.append("long_500k")
     return [SHAPES[n] for n in names]
-
-
-def all_cells():
-    return [(a, s) for a in ARCH_IDS for s in shapes_for(a)]

@@ -417,7 +417,7 @@ def live_fifo_rank(sema_ids: jax.Array, tickets: jax.Array,
       4. scatter back through the inverse permutation.
 
     The former O(N²) pairwise comparison is kept as
-    :func:`live_fifo_rank_pairwise` (equivalence tests + benchmarks).
+    :func:`live_fifo_rank_pairwise`, the oracle of the equivalence tests.
     Tickets are assumed unique within a tenant (they are consecutive
     counter values by construction).
     """

@@ -1,7 +1,6 @@
 """End-to-end training driver (the (b) deliverable's e2e entry point).
 
-Wires every substrate together on whatever devices exist (1 CPU here; the
-production mesh via the dry-run):
+Wires every substrate together on whatever devices exist:
 
     data: SyntheticLM → DataLoader (TWA bounded buffer, FIFO, deterministic)
     model: any --arch config (reduced by default so CPU runs in minutes)

@@ -137,8 +137,7 @@ def blockwise_attention(q, k, v, q_positions, kv_positions, *, window: int = 0,
     l0 = jnp.zeros((B, H, Sq), jnp.float32)
 
     if nblk == 1:
-        # single block — direct call (no while loop; keeps cost_analysis
-        # exact for the dry-run analysis lowering)
+        # single block — direct call, no one-trip loop
         acc, m, l = _online_softmax_block((acc0, m0, l0), scale, qh, kh, vh, mask_for(kv_positions))
     else:
         kh = kh.reshape(B, H, nblk, kv_block, hd).transpose(2, 0, 1, 3, 4)

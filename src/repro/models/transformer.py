@@ -2,7 +2,7 @@
 
 A model is ``prefix + unit×num_units + suffix`` of blocks (configs/base.py).
 The repeating unit is lax.scan'ed with stacked parameters → HLO size is O(1)
-in depth (essential for 80-layer models on the 512-device dry-run) and the
+in depth (essential for compiling 80-layer models) and the
 scan body is remat'ed (selective activation checkpointing).
 
 Modes (static):
@@ -357,12 +357,8 @@ def _group_size(u: int) -> int:
 
 
 def forward_hidden(params, cfg: ModelConfig, x, positions, caches=None, mode="train",
-                   remat="dots", unroll_units: bool = False, kv_block: int = 1024):
-    """x: (B,S,D) input embeddings → (h, new_caches, aux).
-
-    ``unroll_units`` unrolls the layer scan (dry-run analysis lowering only:
-    while-loop bodies are counted once by XLA cost analysis, so the roofline
-    lowering unrolls every static-trip-count loop)."""
+                   remat="dots", kv_block: int = 1024):
+    """x: (B,S,D) input embeddings → (h, new_caches, aux)."""
     aux_total = dict(ZERO_AUX)
     new_caches = {"prefix": [], "suffix": []} if caches is not None else None
 
@@ -375,14 +371,13 @@ def forward_hidden(params, cfg: ModelConfig, x, positions, caches=None, mode="tr
             new_caches["prefix"].append(nc)
 
     if cfg.num_units > 0:
-        unroll = cfg.num_units if unroll_units else 1
         if caches is None:
 
             def unit_fn(xc, up):
                 xo, _, aux = _apply_unit(cfg, up, xc, positions, None, mode, kv_block)
                 return xo, aux
 
-            if remat == "2level" and mode == "train" and not unroll_units:
+            if remat == "2level" and mode == "train":
                 # nested (√L) activation checkpointing: scan over unit GROUPS
                 # (outer checkpoint saves one carry per group) with a
                 # checkpointed per-unit scan inside (backward re-runs one
@@ -406,7 +401,7 @@ def forward_hidden(params, cfg: ModelConfig, x, positions, caches=None, mode="tr
                     unit_fn = jax.checkpoint(
                         unit_fn, policy=getattr(jax.checkpoint_policies, pol) if pol else None
                     )
-                x, auxs = jax.lax.scan(unit_fn, x, params["units"], unroll=unroll)
+                x, auxs = jax.lax.scan(unit_fn, x, params["units"])
             aux_total = _add_aux(aux_total, jax.tree.map(jnp.sum, auxs))
         else:
 
@@ -415,8 +410,7 @@ def forward_hidden(params, cfg: ModelConfig, x, positions, caches=None, mode="tr
                 xo, ncs, aux = _apply_unit(cfg, up, xc, positions, uc, mode, kv_block)
                 return xo, (ncs, aux)
 
-            x, (ncs, auxs) = jax.lax.scan(unit_fn_c, x, (params["units"], caches["units"]),
-                                          unroll=unroll)
+            x, (ncs, auxs) = jax.lax.scan(unit_fn_c, x, (params["units"], caches["units"]))
             new_caches["units"] = ncs
             aux_total = _add_aux(aux_total, jax.tree.map(jnp.sum, auxs))
 
@@ -525,13 +519,13 @@ def chunked_ce_loss(h, head, labels, mask, chunk: int = 512, z_weight: float = 0
     return loss_sum / cnt + z_weight * z_sum / cnt, cnt
 
 
-def train_loss(params, cfg: ModelConfig, batch, remat="dots", unroll_units=False,
-               kv_block: int = 1024, ce_chunk: int = 512):
+def train_loss(params, cfg: ModelConfig, batch, remat="dots", kv_block: int = 1024,
+               ce_chunk: int = 512):
     """Full training objective: chunked CE + MoE aux losses. Returns
     (loss, metrics)."""
     x, positions, mask = embed_inputs(params, cfg, batch)
     h, _, aux = forward_hidden(params, cfg, x, positions, None, "train", remat=remat,
-                               unroll_units=unroll_units, kv_block=kv_block)
+                               kv_block=kv_block)
     ce, cnt = chunked_ce_loss(h, lm_head(params, cfg), batch["labels"], mask,
                               chunk=ce_chunk, z_weight=cfg.z_loss_weight,
                               logits_div=cfg.logits_div)
@@ -551,11 +545,11 @@ def train_loss(params, cfg: ModelConfig, batch, remat="dots", unroll_units=False
 # ---------------------------------------------------------------- serving ---
 
 
-def prefill(params, cfg: ModelConfig, batch, caches, unroll_units=False, kv_block: int = 1024):
+def prefill(params, cfg: ModelConfig, batch, caches, kv_block: int = 1024):
     """Full-context forward that fills caches; returns (last-pos logits, caches)."""
     x, positions, _ = embed_inputs(params, cfg, batch)
     h, caches, _ = forward_hidden(params, cfg, x, positions, caches, "prefill", remat="none",
-                                  unroll_units=unroll_units, kv_block=kv_block)
+                                  kv_block=kv_block)
     logits = jnp.einsum("bd,dv->bv", h[:, -1, :], lm_head(params, cfg).astype(h.dtype))
     return _logits(cfg, logits), caches
 
@@ -592,15 +586,14 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, positions, caches, row):
     return _logits(cfg, logits), caches
 
 
-def decode_step(params, cfg: ModelConfig, tokens, positions, caches, unroll_units=False):
+def decode_step(params, cfg: ModelConfig, tokens, positions, caches):
     """tokens (B,1) int32, positions (B,1) int32 → (logits (B,V) f32, caches).
 
     A ring that is not sequence sharded comes back from the layers as its
     new entries alone, and each leaf takes them with one write at the slot
     (`cache.kv_cache_write_slot`), in place where the caches are donated."""
     x = _scale_embed(cfg, params["embed"][tokens])
-    h, new, _ = forward_hidden(params, cfg, x, positions, caches, "decode", remat="none",
-                               unroll_units=unroll_units)
+    h, new, _ = forward_hidden(params, cfg, x, positions, caches, "decode", remat="none")
     if not seq_shard_axes():
         new = _map_block_caches(
             lambda s, ax, c, n: kv_cache_write_slot(c, n, ax) if s.kind in ("attn", "moe") else n,

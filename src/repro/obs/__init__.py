@@ -14,9 +14,8 @@ sample stream:
   pluggable per-round record consumers;
 * :class:`TenantSLO` / :class:`EngineObs` — per-tenant TTFT/TPOT event
   tracking keyed on the engine's virtual ``clock=`` and SLO-attainment
-  reporting, consumed by ``scheduler.telemetry()`` (the ``slo`` key),
-  ``benchmarks/serving_bench.run_slo``, and
-  ``examples/serve_multitenant.py --trace``;
+  reporting, consumed by ``scheduler.telemetry()`` (the ``slo`` key)
+  and ``examples/serve_multitenant.py --trace``;
 * :class:`TraceBuffer` / :func:`build_spans` / :func:`to_perfetto`
   (PR 10) — per-request span trees from the in-scan event table, with
   Chrome-trace export and critical-path breakdowns;

@@ -11,7 +11,6 @@ property-tested in tests/test_obs.py).
 :func:`aggregate` is the one entry point: give it the per-replica
 ``EngineObs`` objects (plus optional router telemetry for lease-headroom
 and migration-latency sections) and get the fleet report consumed by
-``benchmarks/serving_bench.run_cluster`` and
 ``examples/serve_multitenant.py --cluster``.
 """
 

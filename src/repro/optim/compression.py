@@ -9,8 +9,7 @@ dominant collective.  We therefore reduce gradients hierarchically:
     q         = round((g_local + e)/scale)       # int8-range values
     q_sum     = psum(q as int16, "pod")          # 2-byte wire (4-byte fp32 → 2x;
                                                  # real HW reduces the int8
-                                                 # payload → 4x, noted in
-                                                 # EXPERIMENTS.md)
+                                                 # payload → 4x)
     g_global  = q_sum * scale / n_pods
     e'        = (g_local + e) - q*scale          # error feedback (stays local)
 

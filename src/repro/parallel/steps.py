@@ -1,5 +1,5 @@
-"""Step factories: jit-ready train / prefill / decode steps with the
-distribution features wired in:
+"""Train step factory: a jit-ready train step with the distribution
+features wired in:
 
   * gradient accumulation via lax.scan over microbatches (activation memory
     control for the 16 GB/v5e budget; XLA overlaps each microbatch's
@@ -11,18 +11,14 @@ distribution features wired in:
   * optional hierarchical int8 error-feedback gradient compression across
     the *pod* axis (shard_map manual over "pod", auto over data/model —
     intra-pod reduction stays fp32 on fast ICI, inter-pod crosses DCI
-    quantized; see optim/compression.py);
-  * decode steps use KV-sequence ("flash-decode") sharding — rules set via
-    axis_rules per shape (batch=1 long-context spreads seq over data+model).
+    quantized; see optim/compression.py).
 
-Mode/shape-specific rule overrides keep one model code path for all 40
-(arch × shape) dry-run cells.
+Shape-specific rule overrides (`_mode_rules`) keep one model code path for
+every (arch × shape) pair.
 """
 
 from __future__ import annotations
 
-import math
-from functools import partial
 from typing import Any, NamedTuple
 
 import jax
@@ -30,19 +26,11 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..configs.registry import ShapeSpec
-from ..models.transformer import (
-    decode_step as model_decode_step,
-    init_caches,
-    init_params,
-    prefill as model_prefill,
-    train_loss,
-)
+from ..models.transformer import init_params, train_loss
 from ..optim.adamw import AdamWConfig, OptState, adamw_update, init_opt_state
 from ..optim.compression import compress_psum, init_residuals
 from .sharding import (
     axis_rules,
-    batch_pspecs,
-    cache_pspecs,
     constrain,
     constrain_tree,
     mesh_axes,
@@ -60,17 +48,9 @@ class StepConfig(NamedTuple):
     act_budget_bytes: float = 6e9
     kv_block: int = 1024  # flash-attention KV block (train/prefill)
     ce_chunk: int = 512  # chunked-CE sequence chunk
-    analysis: bool = False  # dry-run analysis lowering: unroll every
-    #   static-trip loop (units scan, attention KV scan, CE chunk scan,
-    #   accumulation) so cost_analysis counts true FLOPs/bytes/collectives.
 
 
 # --------------------------------------------------------------- helpers ----
-
-
-def dp_size() -> int:
-    axes = mesh_axes()
-    return axes.get("pod", 1) * axes.get("data", 1)
 
 
 def est_train_act_bytes(cfg: ModelConfig, tokens_micro: float, tp: int) -> float:
@@ -99,8 +79,7 @@ def est_train_act_bytes(cfg: ModelConfig, tokens_micro: float, tp: int) -> float
 
 
 def default_step_config(cfg: ModelConfig, shape: ShapeSpec, dp: int, **over) -> StepConfig:
-    """Pick accumulation and remat for the v5e 16 GB budget (the dry-run
-    additionally auto-doubles accum_steps if memory_analysis disagrees)."""
+    """Pick accumulation and remat for the v5e 16 GB budget."""
     sc = StepConfig()
     if shape.kind == "train":
         axes = mesh_axes()
@@ -118,19 +97,15 @@ def default_step_config(cfg: ModelConfig, shape: ShapeSpec, dp: int, **over) -> 
 
 
 def _mode_rules(cfg: ModelConfig, shape: ShapeSpec):
-    """Logical-rule overrides per shape: batch axes must divide global_batch;
-    long-context (batch=1) spreads the KV sequence over data+model."""
+    """Logical-rule overrides per shape: batch axes must divide global_batch."""
     axes = mesh_axes()
-    batch_rule: Any = ("pod", "data")
     prod = 1
     picked = []
     for a in ("pod", "data"):
         if a in axes and shape.global_batch % (prod * axes[a]) == 0:
             picked.append(a)
             prod *= axes[a]
-    batch_rule = tuple(picked) if picked else None
-    seq_kv = ("data", "model") if (shape.kind != "train" and "data" in axes and prod == 1) else ("model",)
-    return dict(batch=batch_rule, seq_kv=seq_kv)
+    return dict(batch=tuple(picked) if picked else None, seq_kv=("model",))
 
 
 # ---------------------------------------------------------- train step ------
@@ -149,10 +124,6 @@ def make_train_state(key, cfg: ModelConfig, sc: StepConfig) -> TrainState:
     return TrainState(params, opt, res)
 
 
-def abstract_train_state(cfg: ModelConfig, sc: StepConfig) -> TrainState:
-    return jax.eval_shape(lambda: make_train_state(jax.random.PRNGKey(0), cfg, sc))
-
-
 def train_state_pspecs(state: TrainState, sc: StepConfig):
     pspec = param_pspecs(state.params, fsdp=sc.fsdp)
     res_spec = param_pspecs(state.residuals, fsdp=sc.fsdp) if state.residuals else {}
@@ -168,13 +139,11 @@ def make_train_step(cfg: ModelConfig, shape: ShapeSpec, sc: StepConfig,
     """Returns train_step(state, batch) → (state', metrics). Jit/pjit-ready;
     call under an active mesh (jax.set_mesh) or on a single device."""
     rules = _mode_rules(cfg, shape)
-    A = 1 if sc.analysis else sc.accum_steps
-    kv_block = 10**9 if sc.analysis else sc.kv_block
-    ce_chunk = 10**9 if sc.analysis else sc.ce_chunk
+    A = sc.accum_steps
 
     def loss_fn(params, mb):
-        return train_loss(params, cfg, mb, remat=sc.remat, unroll_units=sc.analysis,
-                          kv_block=kv_block, ce_chunk=ce_chunk)
+        return train_loss(params, cfg, mb, remat=sc.remat, kv_block=sc.kv_block,
+                          ce_chunk=sc.ce_chunk)
 
     def grads_and_metrics(params, batch):
         """Microbatch-accumulated fp32 grads (scan when A > 1)."""
@@ -263,78 +232,3 @@ def make_train_step(cfg: ModelConfig, shape: ShapeSpec, sc: StepConfig,
             return TrainState(new_params, new_opt, new_res), metrics
 
     return train_step_compressed
-
-
-# ------------------------------------------------------------ serve steps ---
-
-
-def make_prefill_step(cfg: ModelConfig, shape: ShapeSpec, sc: StepConfig = StepConfig()):
-    rules = _mode_rules(cfg, shape)
-    kv_block = 10**9 if sc.analysis else sc.kv_block
-
-    def prefill_step(params, batch, caches):
-        with axis_rules(**rules):
-            logits, caches = model_prefill(params, cfg, batch, caches,
-                                           unroll_units=sc.analysis, kv_block=kv_block)
-            if mesh_axes():
-                caches = constrain_tree(caches, cache_pspecs(caches))
-            return logits, caches
-
-    return prefill_step
-
-
-def make_decode_step(cfg: ModelConfig, shape: ShapeSpec, sc: StepConfig = StepConfig()):
-    rules = _mode_rules(cfg, shape)
-
-    def decode_step(params, tokens, positions, caches):
-        with axis_rules(**rules):
-            logits, caches = model_decode_step(params, cfg, tokens, positions, caches,
-                                               unroll_units=sc.analysis)
-            if mesh_axes():
-                caches = constrain_tree(caches, cache_pspecs(caches))
-            return logits, caches
-
-    return decode_step
-
-
-# ------------------------------------------------------------ input specs ---
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeSpec, cache_dtype=jnp.bfloat16):
-    """ShapeDtypeStruct stand-ins for every model input of this (arch, shape)
-    cell — weak-type-correct, shardable, no device allocation.
-
-    train   → {"batch": {tokens, labels, [patch/frame embeds]}}
-    prefill → {"batch": …, "caches": zero-initialized cache tree}
-    decode  → {"tokens", "positions", "caches"}
-    """
-    B, S = shape.global_batch, shape.seq_len
-    sds = jax.ShapeDtypeStruct
-    emb_dtype = jnp.bfloat16
-
-    def batch_struct(seq_len):
-        b = {"tokens": sds((B, seq_len), jnp.int32), "labels": sds((B, seq_len), jnp.int32)}
-        if cfg.frontend == "vision":
-            b["patch_embeds"] = sds((B, cfg.n_patches, cfg.d_model), emb_dtype)
-            b["labels"] = sds((B, seq_len + cfg.n_patches), jnp.int32)
-        elif cfg.frontend == "audio":
-            b = {
-                "frame_embeds": sds((B, seq_len, cfg.d_model), emb_dtype),
-                "labels": sds((B, seq_len), jnp.int32),
-            }
-        return b
-
-    if shape.kind == "train":
-        return {"batch": batch_struct(S)}
-
-    capacity = S + (cfg.n_patches if cfg.frontend == "vision" else 0)
-    caches = jax.eval_shape(partial(init_caches, cfg, B, capacity, cache_dtype))
-    if shape.kind == "prefill":
-        b = batch_struct(S)
-        b.pop("labels", None)  # prefill consumes no labels
-        return {"batch": b, "caches": caches}
-    return {
-        "tokens": sds((B, 1), jnp.int32),
-        "positions": sds((B, 1), jnp.int32),
-        "caches": caches,
-    }

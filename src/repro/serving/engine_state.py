@@ -20,9 +20,9 @@ round fuses
       admissions `take`, and ``grant − ticket`` is the physical free-slot
       count by the paper's counter identity;
   (c) decode + sampling through a caller-supplied jittable ``token_fn``
-      (`make_paged_attn_token_fn` demonstrates paged single-token decode
-      attention over a per-slot ring KV cache with in-graph prompt
-      prefill);
+      (`paged_pool_token_fn` demonstrates paged single-token decode
+      attention over the block pool, `paged_pool_admit_fn` its in-graph
+      prompt prefill);
   (d) completion AND deadline detection: sequences that hit ``max_new``
       or whose deadline passes mid-decode are tombstoned in-graph and
       their slots posted back into the SAME scanned round machinery —
@@ -73,7 +73,7 @@ semaphore a second time at block granularity (`core.functional.BlockPool`):
   * the per-slot **block tables** (``EngineState.kv.tbl``) map slot ×
     block-ordinal → pool block id; `kernels/paged_decode` streams
     attention over exactly the live blocks (bytes ∝ live tokens, not
-    ∝ S·C as with the dense rings).
+    ∝ S·C as with per-slot rings).
 
 Continuous chunked prefill (incremental allocation + the stall/park policy)
 ---------------------------------------------------------------------------
@@ -460,7 +460,7 @@ class EngineState(NamedTuple):
     round_no: jax.Array  # i32 scalar — global engine round counter
     backlog: Backlog
     slots: Slots
-    kv: Optional[KVPool] = None  # block-paged KV pool (None = dense rings)
+    kv: Optional[KVPool] = None  # block-paged KV pool (None = no pool)
     stalls: Optional[jax.Array] = None  # i32 — cumulative parked slot-rounds
     chunks: Optional[jax.Array] = None  # i32 — cumulative prefill chunks
     ring: Optional[TelemetryRing] = None  # in-scan telemetry (None = off)
@@ -1234,59 +1234,17 @@ def rid_token_fn(model, state: EngineState):
 
 
 def zero_token_fn(model, state: EngineState):
-    """The serving-bench toy model (host path: zero logits, zero sample)."""
+    """The default toy model: every slot samples token 0 (the host path's
+    zero logits, zero sample)."""
     return jnp.zeros_like(state.slots.token), model
-
-
-def make_paged_attn_model(key, vocab: int, d: int, n_slots: int,
-                          capacity: int):
-    """Single-layer attention LM over a per-slot ring KV cache — the
-    demonstration that real paged decode attention + sampling runs inside
-    the scanned round (the `kernels/decode_attention` access pattern;
-    ref-path attention keeps the scan CPU-lowerable)."""
-    k1, k2 = jax.random.split(key)
-    return {
-        "emb": jax.random.normal(k1, (vocab, d), jnp.float32) * 0.05,
-        "wo": jax.random.normal(k2, (d, d), jnp.float32) * 0.05,
-        "k": jnp.zeros((n_slots, capacity, 1, d), jnp.float32),
-        "v": jnp.zeros((n_slots, capacity, 1, d), jnp.float32),
-        "pos": jnp.full((n_slots, capacity), -1, jnp.int32),
-    }
-
-
-def paged_attn_admit_fn(model, state: EngineState, rows, mask, slots):
-    """In-graph prefill: write the admitted rows' prompt embeddings into
-    their slots' KV rows (bulk masked write — one scatter per round for
-    ALL admitted slots, the batched counterpart of the host engine's
-    per-request `prefill_fn`)."""
-    bl = state.backlog
-    C = model["pos"].shape[1]
-    P = bl.prompt.shape[1]
-    S = slots.shape[0]
-    ptoks = bl.prompt[rows]                       # (S, P)
-    plens = bl.prompt_len[rows]                   # (S,)
-    pe = model["emb"][ptoks][:, :, None, :]       # (S, P, 1, d)
-    pad = ((0, 0), (0, C - P), (0, 0), (0, 0))
-    kc = jnp.pad(pe, pad)
-    vc = jnp.pad(pe, pad)                         # tied K/V embeddings
-    posc = jnp.where(jnp.arange(C)[None, :] < plens[:, None],
-                     jnp.arange(C, dtype=jnp.int32)[None, :], -1)
-    tgt = jnp.where(mask, slots, S)               # out-of-range → dropped
-    return {
-        **model,
-        "k": model["k"].at[tgt].set(kc, mode="drop"),
-        "v": model["v"].at[tgt].set(vc, mode="drop"),
-        "pos": model["pos"].at[tgt].set(posc, mode="drop"),
-    }
 
 
 def make_paged_pool_model(key, vocab: int, d: int, num_blocks: int,
                           block_size: int):
-    """Single-layer attention LM over the SHARED block-paged KV pool — the
-    successor of :func:`make_paged_attn_model`'s per-slot rings (kept as
-    the dense baseline): KV lives in (NB, BS) pool blocks owned by the TWA
-    block semaphore; which slot reads/writes which block is entirely the
-    engine's block tables (`EngineState.kv.tbl`)."""
+    """Single-layer attention LM over the SHARED block-paged KV pool: KV
+    lives in (NB, BS) pool blocks owned by the TWA block semaphore; which
+    slot reads/writes which block is entirely the engine's block tables
+    (`EngineState.kv.tbl`)."""
     k1, k2 = jax.random.split(key)
     return {
         "emb": jax.random.normal(k1, (vocab, d), jnp.float32) * 0.05,
@@ -1453,27 +1411,3 @@ def _chunked_prefill_step(model, state: EngineState, window: int):
     logits = (o[:, 0] @ model["wo"]) @ model["emb"].T
     toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return toks, {**model, "kp": kp, "vp": vp}
-
-
-def paged_attn_token_fn(model, state: EngineState):
-    """Paged single-token decode: write the current token's KV at the ring
-    cursor, attend over the slot's cache (ref-path decode attention), and
-    greedy-sample the next token."""
-    from ..kernels.ref import decode_attention_ref
-
-    sl = state.slots
-    S, C = model["pos"].shape
-    cur = model["emb"][sl.token]                  # (S, d)
-    ring = sl.pos % C                             # per-slot write cursor
-    rows_i = jnp.arange(S, dtype=jnp.int32)
-    wr = sl.busy
-    k = model["k"].at[rows_i, ring, 0].set(
-        jnp.where(wr[:, None], cur, model["k"][rows_i, ring, 0]))
-    v = model["v"].at[rows_i, ring, 0].set(
-        jnp.where(wr[:, None], cur, model["v"][rows_i, ring, 0]))
-    pos = model["pos"].at[rows_i, ring].set(
-        jnp.where(wr, sl.pos, model["pos"][rows_i, ring]))
-    o = decode_attention_ref(cur[:, None, :], k, v, pos, sl.pos)  # (S,1,d)
-    logits = (o[:, 0] @ model["wo"]) @ model["emb"].T
-    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return toks, {**model, "k": k, "v": v, "pos": pos}

@@ -37,8 +37,7 @@ preemption, the QoS admission round, TWA slot assignment, decode+sample,
 completion — runs as ONE jitted `lax.scan` over a donated on-device
 `serving.engine_state.EngineState` pytree, draining K decoded tokens per
 host sync instead of one.  Round-for-round identical to K `step()` calls
-(tests/test_megastep.py); `benchmarks/serving_bench.py` measures the
-speedup vs K.
+(tests/test_megastep.py).
 
 The engine below is deliberately model-agnostic: `step_fn` is any callable
 (tokens, positions, caches) → (logits, caches); tests drive it with a tiny
@@ -308,11 +307,10 @@ class ContinuousBatchingEngine:
             self._chunk, self._budget = int(ch), int(bu)
             # optional third element: the commitment watermark in BLOCKS
             # (aggregate outstanding worst-case demand admission may keep
-            # in flight).  Default 9/16 of the pool: the measured sweet
-            # spot between utilization (higher watermark ⇒ more resident
-            # sequences ⇒ more written blocks) and safety-chain slack
-            # (lower ⇒ fewer parks serializing the endgame) — see
-            # benchmarks/serving_bench.run_longprompt.
+            # in flight).  Default 9/16 of the pool: a balance between
+            # utilization (higher watermark ⇒ more resident sequences ⇒
+            # more written blocks) and safety-chain slack (lower ⇒ fewer
+            # parks serializing the endgame).
             self._kv_commit = int(cw[0]) if cw \
                 else max(1, int(kv_pool[0]) * 9 // 16)
         if kv_pool is not None:

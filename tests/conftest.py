@@ -1,7 +1,7 @@
 """Test bootstrap: put src/ on sys.path (tests run with or without
 PYTHONPATH=src), make the tests dir importable (the hypothesis fallback
-shim lives here), and keep jax on the default single CPU device — the
-512-device XLA flag is set ONLY by launch/dryrun.py."""
+shim lives here), and keep jax on the default single CPU device — a test
+that needs more devices forces them in a subprocess."""
 
 import os
 import sys

@@ -289,6 +289,33 @@ def test_engine_weighted_fcfs_admission():
     assert eng.stats.backlog_skipped > eng.stats.backlog_scans
 
 
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_engine_admission_shares_track_weights(use_kernel):
+    """150 requests per tenant at weights 4:2:1 over 6 slots: while every
+    tenant still has a backlog, each tenant's share of admissions is within
+    10% of its weight's share, through the host queue walk and through the
+    fused admission round alike."""
+    weights = {"gold": 4.0, "silver": 2.0, "bronze": 1.0}
+    eng = ContinuousBatchingEngine(
+        lambda active: np.zeros(len(active)), lambda r: None, n_slots=6,
+        tenants=weights, use_kernel=use_kernel)
+    reqs, rid = [], 0
+    for _ in range(150):
+        for t in weights:
+            reqs.append(Request(rid=rid, prompt=[1], max_new_tokens=3,
+                                tenant_id=t))
+            rid += 1
+    eng.submit_batch(reqs)
+    saturated = lambda: all(d > 0 for d in eng._tenant_live)
+    _run_engine(eng, reqs, until=lambda: not saturated())
+    assert not saturated()  # a backlog drained, not the step cap
+    total = sum(eng.tenant_admitted.values())
+    wsum = sum(weights.values())
+    for t, w in weights.items():
+        share = eng.tenant_admitted[t] / total
+        assert abs(share - w / wsum) / (w / wsum) < 0.10, (t, share)
+
+
 def test_engine_deadline_miss_tombstoned():
     """A queued request whose deadline passes is expired (client unblocked,
     stats counted) and never blocks later live requests of its tenant."""

@@ -48,6 +48,7 @@ from repro.core.functional import (
 from repro.serving.engine_state import (
     chunked_prefill_token_fn,
     engine_round,
+    make_chunked_prefill_token_fn,
     make_paged_pool_model,
     paged_pool_admit_fn,
     paged_pool_token_fn,
@@ -385,7 +386,6 @@ def test_submit_rejects_over_capacity_and_over_prompt_cap():
             kv_pool=(8, 4), chunked_prefill=(0, 8))
     # a token_fn whose static scatter window is narrower than the engine
     # chunk would silently drop chunk tails — rejected at launch
-    from repro.serving.engine_state import make_chunked_prefill_token_fn
     with pytest.raises(ValueError, match="chunk window"):
         eng.megastep(1, token_fn=make_chunked_prefill_token_fn(2))
 
@@ -421,3 +421,45 @@ def test_telemetry_chunked_gauges():
     tel = eng.telemetry()
     assert tel["pool_utilization"] == 0.0 and tel["kv_blocks_free"] == 8
     assert tel["parked_slots"] == 0
+
+
+def test_chunked_pool_utilization_beats_upfront_reservation():
+    """A long-prompt mix (16 requests, prompts of 40-79 tokens, decodes of
+    64-127) over one 64×8 pool and 8 slots: chunked prefill with
+    incremental allocation keeps a higher mean pool utilization than
+    worst-case up-front reservation, which holds each sequence's unwritten
+    decode tail for its whole life, and both modes serve the same tokens."""
+    NB, BS, MB = 64, 8, 26
+    S, K, CHUNK, BUDGET = 8, 16, 24, 96
+    vocab, n_req = 50, 16
+    rng = np.random.default_rng(9)
+    plens = rng.integers(40, 80, n_req)
+    mxs = rng.integers(64, 128, n_req)
+    rng_p = np.random.default_rng(11)
+    prompts = [list(rng_p.integers(1, vocab, p)) for p in plens]
+
+    def drain(chunked: bool):
+        eng = ContinuousBatchingEngine(
+            lambda a: None, lambda r: None, S, tenants={"a": 1.0},
+            kv_pool=(NB, BS, MB), prompt_cap=128,
+            chunked_prefill=(CHUNK, BUDGET) if chunked else None)
+        eng.megastep_model = make_paged_pool_model(
+            jax.random.PRNGKey(0), vocab=vocab, d=8, num_blocks=NB,
+            block_size=BS)
+        tok_fn = (make_chunked_prefill_token_fn(CHUNK) if chunked
+                  else paged_pool_token_fn)
+        adm_fn = None if chunked else paged_pool_admit_fn
+        reqs = [Request(rid=i, prompt=list(p), max_new_tokens=int(mxs[i]),
+                        tenant_id="a") for i, p in enumerate(prompts)]
+        eng.submit_batch(reqs)
+        utils = []
+        while eng.stats.finished < n_req:
+            eng.megastep(K, token_fn=tok_fn, admit_fn=adm_fn)
+            utils.append(eng.telemetry()["pool_utilization"])
+        live = [u for u in utils if u > 0]  # the emptying tail left out
+        return [r.out_tokens for r in reqs], sum(live) / len(live)
+
+    toks_u, util_u = drain(False)
+    toks_c, util_c = drain(True)
+    assert toks_c == toks_u
+    assert util_c > util_u, (util_c, util_u)

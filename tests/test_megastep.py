@@ -30,12 +30,7 @@ try:  # hypothesis is an optional test dependency (pyproject `test` extra)
 except ImportError:
     from _hypothesis_shim import given, settings, strategies as st
 
-from repro.serving.engine_state import (
-    make_paged_attn_model,
-    paged_attn_admit_fn,
-    paged_attn_token_fn,
-    rid_token_fn,
-)
+from repro.serving.engine_state import rid_token_fn
 from repro.serving.scheduler import ContinuousBatchingEngine, Request
 
 WEIGHTS = {"gold": 4.0, "silver": 2.0, "bronze": 1.0}
@@ -342,38 +337,3 @@ def test_telemetry_queue_depth_qos():
     while eng.stats.finished < 40:
         eng.step(lambda lg: np.zeros(len(lg), np.int64))
     assert eng.telemetry()["queue_depth"] == 0
-
-
-# ------------------------------------------------- paged attention ----------
-
-
-def _attn_run(n_slots, K, vocab=50, n_req=10):
-    eng = ContinuousBatchingEngine(
-        lambda a: None, lambda r: None, n_slots=n_slots,
-        tenants={"a": 1.0}, clock=lambda: 0.0)
-    eng.megastep_model = make_paged_attn_model(
-        jax.random.PRNGKey(0), vocab=vocab, d=16, n_slots=n_slots,
-        capacity=64)
-    rng = np.random.default_rng(0)
-    reqs = [Request(rid=i, prompt=list(rng.integers(1, vocab, 5)),
-                    max_new_tokens=6, tenant_id="a") for i in range(n_req)]
-    eng.submit_batch(reqs)
-    launches = 0
-    while eng.stats.finished < n_req and launches < 100:
-        eng.megastep(K, token_fn=paged_attn_token_fn,
-                     admit_fn=paged_attn_admit_fn)
-        launches += 1
-    assert eng.stats.finished == n_req
-    return [r.out_tokens for r in reqs]
-
-
-def test_paged_attention_megastep():
-    """Real paged decode attention + sampling runs inside the scanned
-    round (in-graph prompt prefill at admission, ring-cursor KV writes),
-    and per-request streams are invariant to slot count and K — the
-    decode depends only on the request's own tokens, never on which slot
-    or scan round served it."""
-    a = _attn_run(n_slots=4, K=8)
-    assert all(len(t) == 6 and all(0 <= x < 50 for x in t) for t in a)
-    b = _attn_run(n_slots=2, K=4)
-    assert a == b

@@ -3,7 +3,7 @@ equivalent to single-device execution, grad accumulation must match the
 unaccumulated step, and elastic re-sharding must be bit-exact.
 
 Uses a forced 8-device host platform in a SUBPROCESS so the main test
-process keeps the default single CPU device (the dry-run flag rule)."""
+process keeps the default single CPU device."""
 
 from __future__ import annotations
 

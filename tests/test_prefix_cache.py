@@ -47,6 +47,7 @@ from repro.core.functional import (
 from repro.resilience.recovery import exit_audit
 from repro.serving.engine_state import (
     chunked_prefill_token_fn,
+    make_chunked_prefill_token_fn,
     make_paged_pool_model,
     rid_token_fn,
 )
@@ -435,3 +436,51 @@ def test_submit_validates_against_post_divergence_demand():
                     tenant_id="gold")
     with pytest.raises(ValueError):
         eng.submit(alien)
+
+
+def test_shared_system_prompt_halves_prefill_tokens():
+    """Each of 16 requests opens with the same 224-token system prompt and
+    a 9-token tail; after the first wave every other request repeats an
+    earlier prompt verbatim while its holder still decodes.  Over one pool,
+    sharing prefills under half the prompt tokens that no sharing does,
+    the verbatim repeats hit the cache, and without sharing nothing hits."""
+    NB, BS, MB = 256, 8, 32
+    S, K, CHUNK, BUDGET = 8, 16, 24, 48
+    vocab, PRE, TAIL, n_req = 50, 224, 9, 16
+    # the shared chain's 28 direct-mapped homes are pairwise distinct at
+    # this seed, so no same-sweep collision cuts the chain
+    sysp = list(np.random.default_rng(4).integers(1, vocab, PRE))
+    rng = np.random.default_rng(3)
+    mxs = [int(m) for m in rng.integers(3, 8, n_req)]  # staggered decodes
+    prompts = []
+    for i in range(n_req):
+        if i >= S and i % 2 == 1:
+            prompts.append(list(prompts[i - 2]))
+        else:
+            prompts.append(sysp + list(rng.integers(1, vocab, TAIL)))
+
+    def drain(prefix: int):
+        eng = ContinuousBatchingEngine(
+            lambda a: None, lambda r: None, S, tenants={"a": 1.0},
+            clock=lambda: 0.0, kv_pool=(NB, BS, MB), prompt_cap=256,
+            chunked_prefill=(CHUNK, BUDGET), prefix_cache=prefix)
+        eng.megastep_model = make_paged_pool_model(
+            jax.random.PRNGKey(0), vocab=vocab, d=8, num_blocks=NB,
+            block_size=BS)
+        tok_fn = make_chunked_prefill_token_fn(CHUNK)
+        reqs = [Request(rid=i, prompt=list(p), max_new_tokens=mxs[i],
+                        tenant_id="a") for i, p in enumerate(prompts)]
+        eng.submit_batch(reqs)
+        prefilled = 0
+        while eng.stats.finished < n_req:
+            eng.megastep(K, token_fn=tok_fn)
+            prefilled += sum(s["prefill_tokens"] for s in eng._last_samples)
+        return eng, reqs, prefilled
+
+    eng_b, reqs_b, pf_b = drain(0)
+    eng_s, reqs_s, pf_s = drain(1024)
+    assert ([len(r.out_tokens) for r in reqs_s]
+            == [len(r.out_tokens) for r in reqs_b] == mxs)
+    assert pf_s < pf_b / 2, (pf_s, pf_b)
+    assert eng_s.stats.prefix_hits > 0
+    assert eng_b.stats.prefix_hits == 0
